@@ -16,7 +16,7 @@ serving shape (256x384-class, PR 1-3's workload):
 Both run ``impl='auto'`` so the serving shape lands on the resident tier
 — which is also where the implicit win compounds: the implicit VMEM
 budget is coupling-only (``resident_fits(implicit=True)``), so shapes the
-dense path must stream (1024x2048 fp32) run resident under a geometry,
+dense path must stream (1024x2560 fp32) run resident under a geometry,
 measured below as ``residentfit_*``.
 
 Hard in-bench asserts (the ISSUE-4 acceptance):
@@ -26,7 +26,7 @@ Hard in-bench asserts (the ISSUE-4 acceptance):
     M*N-sized (largest operand is O((M+N)*d) coordinates; asserted
     against the actual arrays handed to the jit), while the dense path's
     smallest possible cost operand is ``B*M*N*4`` bytes;
-  * dispatch — ``impl='auto'`` routes 1024x2048 fp32 to the resident tier
+  * dispatch — ``impl='auto'`` routes 1024x2560 fp32 to the resident tier
     under the implicit geometry and to the streamed tier dense.
 
 Wall-clock honesty (measured, CPU, fp32, tol-converged ~12-iteration
@@ -160,9 +160,9 @@ def bench_serving_case(B, M, N, d, tol):
 
 
 def bench_resident_fit_expansion(smoke):
-    """The implicit VMEM budget is coupling-only: 1024x2048 fp32 streams
-    dense (16 B/elt > budget) but runs resident implicit (12 B/elt)."""
-    M, N = (256, 512) if smoke else (1024, 2048)
+    """The implicit VMEM budget is coupling-only: 1024x2560 fp32 streams
+    dense (24 B/elt > budget) but runs resident implicit (16 B/elt)."""
+    M, N = (256, 512) if smoke else (1024, 2560)
     cfg = UOTConfig(reg=0.05, reg_m=1.0, num_iters=10)
     rng = np.random.default_rng(1)
     g = PointCloudGeometry.from_points(

@@ -87,6 +87,8 @@ def main(argv=None) -> None:
     args = parser.parse_args(argv)
 
     from repro import obs as obslib
+    from repro.launch import compile_cache
+    compile_cache.enable(pathlib.Path(__file__).resolve().parent.parent)
     from benchmarks import (common, bench_uot, bench_traffic, bench_kernel,
                             bench_memory, bench_distributed,
                             bench_application, bench_moe_router, bench_batch,

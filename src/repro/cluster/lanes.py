@@ -41,7 +41,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.problem import UOTConfig
@@ -71,7 +70,7 @@ class ClusterLaneState:
 
     def device_state(self, d: int) -> ops.LaneState:
         """Device ``d``'s pool as a plain single-device ``LaneState``."""
-        return jax.tree_util.tree_map(lambda x: x[d], self.lanes)
+        return jax.tree_util.tree_map(lambda x: stack_get(x, d), self.lanes)
 
 
 jax.tree_util.register_dataclass(
@@ -110,6 +109,23 @@ def make_cluster_lane_state(num_devices: int, lanes_per_device: int, M: int,
     return ClusterLaneState(lanes=lanes)
 
 
+def stack_set(x: jax.Array, idx, value) -> jax.Array:
+    """``x.at[idx].set(value)`` that keeps ``x``'s sharding: on a mesh the
+    pool stack is sharded along the device axis, and a scatter into it
+    must name its output sharding."""
+    return x.at[idx].set(value, out_sharding=jax.typeof(x).sharding)
+
+
+def stack_get(x: jax.Array, idx) -> jax.Array:
+    """``x[idx]`` of a pool-stack leaf. On a mesh the stack is sharded
+    along the device axis, so the gathered slice must name its sharding:
+    it comes back replicated."""
+    sharding = jax.typeof(x).sharding
+    if sharding.mesh.empty:
+        return x[idx]
+    return x.at[idx].get(out_sharding=NamedSharding(sharding.mesh, P()))
+
+
 @jax.jit
 def cluster_admit(cstate: ClusterLaneState, device, lane, K: jax.Array,
                   a: jax.Array, b: jax.Array, m_valid=None,
@@ -129,17 +145,17 @@ def cluster_admit(cstate: ClusterLaneState, device, lane, K: jax.Array,
                                                 n_valid, st.P.dtype)
     idx = (device, lane)
     return ClusterLaneState(lanes=ops.LaneState(
-        P=st.P.at[idx].set(Kp),
-        colsum=st.colsum.at[idx].set(Kp.astype(jnp.float32).sum(-2)),
-        a=st.a.at[idx].set(ap),
-        b=st.b.at[idx].set(bp),
-        frow=st.frow.at[idx].set(1.0),
-        iters=st.iters.at[idx].set(0),
-        converged=st.converged.at[idx].set(False),
-        active=st.active.at[idx].set(True),
-        m_valid=st.m_valid.at[idx].set(mv),
-        n_valid=st.n_valid.at[idx].set(nv),
-        healthy=st.healthy.at[idx].set(True)))
+        P=stack_set(st.P, idx, Kp),
+        colsum=stack_set(st.colsum, idx, Kp.astype(jnp.float32).sum(-2)),
+        a=stack_set(st.a, idx, ap),
+        b=stack_set(st.b, idx, bp),
+        frow=stack_set(st.frow, idx, 1.0),
+        iters=stack_set(st.iters, idx, 0),
+        converged=stack_set(st.converged, idx, False),
+        active=stack_set(st.active, idx, True),
+        m_valid=stack_set(st.m_valid, idx, mv),
+        n_valid=stack_set(st.n_valid, idx, nv),
+        healthy=stack_set(st.healthy, idx, True)))
 
 
 @jax.jit
@@ -149,17 +165,17 @@ def cluster_evict(cstate: ClusterLaneState, device, lane) -> ClusterLaneState:
     st = cstate.lanes
     idx = (device, lane)
     return ClusterLaneState(lanes=ops.LaneState(
-        P=st.P.at[idx].set(jnp.zeros(st.P.shape[2:], st.P.dtype)),
-        colsum=st.colsum.at[idx].set(0.0),
-        a=st.a.at[idx].set(0.0),
-        b=st.b.at[idx].set(0.0),
-        frow=st.frow.at[idx].set(1.0),
-        iters=st.iters.at[idx].set(0),
-        converged=st.converged.at[idx].set(False),
-        active=st.active.at[idx].set(False),
-        m_valid=st.m_valid.at[idx].set(0),
-        n_valid=st.n_valid.at[idx].set(0),
-        healthy=st.healthy.at[idx].set(True)))
+        P=stack_set(st.P, idx, jnp.zeros(st.P.shape[2:], st.P.dtype)),
+        colsum=stack_set(st.colsum, idx, 0.0),
+        a=stack_set(st.a, idx, 0.0),
+        b=stack_set(st.b, idx, 0.0),
+        frow=stack_set(st.frow, idx, 1.0),
+        iters=stack_set(st.iters, idx, 0),
+        converged=stack_set(st.converged, idx, False),
+        active=stack_set(st.active, idx, False),
+        m_valid=stack_set(st.m_valid, idx, 0),
+        n_valid=stack_set(st.n_valid, idx, 0),
+        healthy=stack_set(st.healthy, idx, True)))
 
 
 def cluster_done(cstate: ClusterLaneState, max_iters: int) -> jax.Array:
@@ -184,9 +200,9 @@ def cluster_poison_device(cstate: ClusterLaneState,
     nan = jnp.nan
     return ClusterLaneState(lanes=dataclasses.replace(
         st,
-        P=st.P.at[device].set(jnp.asarray(nan, st.P.dtype)),
-        colsum=st.colsum.at[device].set(nan),
-        frow=st.frow.at[device].set(nan)))
+        P=stack_set(st.P, device, jnp.asarray(nan, st.P.dtype)),
+        colsum=stack_set(st.colsum, device, nan),
+        frow=stack_set(st.frow, device, nan)))
 
 
 @functools.lru_cache(maxsize=None)
@@ -196,7 +212,7 @@ def _cluster_stepped_fn(mesh: Mesh, axis: str, n_iters: int, cfg: UOTConfig,
 
     The shard_map body squeezes the per-device (1, L, ...) block to a plain
     single-device ``LaneState``, runs the ordinary stepped chunk on it, and
-    restores the device dim. No collectives — check_rep is moot, but False
+    restores the device dim. No collectives — check_vma is moot, but False
     matches the other shard_map solvers. Cached per (mesh, axis, chunk,
     cfg, flavor): building re-wraps shard_map + jit.
     """
@@ -207,8 +223,8 @@ def _cluster_stepped_fn(mesh: Mesh, axis: str, n_iters: int, cfg: UOTConfig,
                                       interpret=interpret, impl=impl)
         return jax.tree_util.tree_map(lambda x: x[None], out)
 
-    sharded = shard_map(advance_block, mesh=mesh, in_specs=(P(axis),),
-                        out_specs=P(axis), check_rep=False)
+    sharded = jax.shard_map(advance_block, mesh=mesh, in_specs=(P(axis),),
+                            out_specs=P(axis), check_vma=False)
     return jax.jit(sharded)
 
 

@@ -138,7 +138,8 @@ from repro.serve.scheduler import (_COUNTER_NAMES, QueueFullError,
 from repro.cluster.lanes import (ClusterLaneState, cluster_admit,
                                  cluster_done, cluster_evict,
                                  cluster_poison_device, cluster_stepped,
-                                 make_cluster_lane_state)
+                                 make_cluster_lane_state, stack_get,
+                                 stack_set)
 
 
 @dataclasses.dataclass
@@ -985,7 +986,7 @@ class ClusterScheduler:
                 M, N = req.shape
                 P = None
                 if healthy[slot]:
-                    P = np.asarray(pool.state.lanes.P[d, l])[:M, :N].copy()
+                    P = np.asarray(stack_get(pool.state.lanes.P, (d, l)))[:M, :N].copy()
                     # host-side double check on the one evicted slice:
                     # poison landing after the convergence latch froze the
                     # lane never crosses the detector's window
@@ -1071,10 +1072,10 @@ class ClusterScheduler:
                     pool.state = ClusterLaneState(
                         lanes=dataclasses.replace(
                             st,
-                            P=st.P.at[d, l].set(
-                                jnp.asarray(jnp.nan, st.P.dtype)),
-                            colsum=st.colsum.at[d, l].set(jnp.nan),
-                            frow=st.frow.at[d, l].set(jnp.nan)))
+                            P=stack_set(st.P, (d, l),
+                                        jnp.asarray(jnp.nan, st.P.dtype)),
+                            colsum=stack_set(st.colsum, (d, l), jnp.nan),
+                            frow=stack_set(st.frow, (d, l), jnp.nan)))
                     return True
         return False
 

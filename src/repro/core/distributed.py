@@ -36,7 +36,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.problem import UOTConfig, rescale_factors
@@ -89,11 +88,11 @@ def rowsharded_fused_solver(mesh: Mesh, axis: str, cfg: UOTConfig, *,
             0, cfg.num_iters, body, (A_blk, colsum))
         return A_blk, colsum
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         solve_shard, mesh=mesh,
         in_specs=(P(axis, None), P(axis), P()),
         out_specs=(P(axis, None), P()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded)
 
 
@@ -132,11 +131,11 @@ def sharded2d_fused_solver(mesh: Mesh, row_axis: str, col_axis: str,
             0, cfg.num_iters, body, (A_blk, colsum))
         return A_blk, colsum
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         solve_shard, mesh=mesh,
         in_specs=(P(row_axis, col_axis), P(row_axis), P(col_axis)),
         out_specs=(P(row_axis, col_axis), P(col_axis)),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded)
 
 
@@ -204,11 +203,11 @@ def rowsharded_overlapped_solver(mesh: Mesh, axis: str, cfg: UOTConfig,
             one_iter, (A_blk, colsum0), None, length=cfg.num_iters)
         return A_blk, colsum
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         solve_shard, mesh=mesh,
         in_specs=(P(axis, None), P(axis), P()),
         out_specs=(P(axis, None), P()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded)
 
 

@@ -82,7 +82,8 @@ def cost_tile(x, xn, y, yn, *, scale: float = 1.0) -> jax.Array:
     return sq
 
 
-def gibbs_tile(x, xn, y, yn, *, reg: float, scale: float = 1.0) -> jax.Array:
+def gibbs_tile(x, xn, y, yn, *, reg: float, scale: float = 1.0,
+               barrier: bool = True) -> jax.Array:
     """``exp(-cost_tile / reg)`` — the Gibbs-kernel tile, computed with the
     exact arithmetic of the two-step dense path (materialize ``C``, then
     exponentiate).
@@ -95,8 +96,14 @@ def gibbs_tile(x, xn, y, yn, *, reg: float, scale: float = 1.0) -> jax.Array:
     the low bit. The barrier pins the exp's input to exactly the value
     the dense path stores. (Rounding, not performance: the barrier cuts
     one fusion edge on an elementwise chain.)
+
+    Compiled Pallas kernels pass ``barrier=False``: Mosaic has no lowering
+    for the barrier and no XLA fusion for it to cut, so on the chip the
+    tile matches the XLA mirror to a tolerance, not bit for bit.
     """
-    sq = jax.lax.optimization_barrier(cost_tile(x, xn, y, yn, scale=scale))
+    sq = cost_tile(x, xn, y, yn, scale=scale)
+    if barrier:
+        sq = jax.lax.optimization_barrier(sq)
     return jnp.exp(-sq / reg)
 
 

@@ -171,10 +171,8 @@ from repro.core.problem import UOTConfig, rescale_factors
 from repro.geometry import Geometry, PointCloudGeometry
 from repro.kernels import (uot_batched, uot_fused, uot_geometry,
                            uot_halfpass, uot_resident, uot_uv_fused)
+from repro.kernels.vmem import VMEM_LIMIT_BYTES
 
-# TPU v5e VMEM is 128 MiB; keep the working set (in + out + accumulators,
-# double-buffered) comfortably under half of it.
-_VMEM_BUDGET_BYTES = 32 * 1024 * 1024
 _LANE = 128       # TPU lane width (minor dim alignment)
 _SUBLANE = 8      # fp32 sublane count (16 for bf16 — see sublane_for)
 
@@ -200,23 +198,79 @@ def _storage(cfg: UOTConfig, storage_dtype):
     return jnp.dtype(storage_dtype if storage_dtype is not None else cfg.dtype)
 
 
+def _vector_bytes(rows: int, cols: int, count: int) -> int:
+    """VMEM of ``count`` (rows, 1) and ``count`` (1, cols) fp32 operands:
+    Mosaic pads a (rows, 1) block to the 128-lane width and a (1, cols)
+    block to 8 sublanes, so an O(M) vector costs 512 bytes per row."""
+    return count * 4 * (rows * _LANE + cols * _SUBLANE)
+
+
+def streamed_vmem_bytes(block_m: int, N: int, itemsize: int = 4,
+                        acc_itemsize: int = 4, *,
+                        implicit: bool = False) -> int:
+    """Scoped VMEM one grid step of the streamed kernels asks Mosaic for.
+
+    The pipeline double-buffers the in and out ``(block_m, N)`` tiles in
+    the storage dtype, and the kernel body holds one ``acc_itemsize``
+    working copy of the tile: ``block_m * N * (4*s + acc)``. On top come
+    the O(block_m + N) factor, marginal and column-sum blocks. The
+    implicit-geometry kernels (``uot_geometry``) load no input tile, but
+    Mosaic keeps about ten ``acc_itemsize`` temporaries of the cost-tile
+    arithmetic and the whole double-buffered ``(N, d)`` column cloud (one
+    lane tile wide for d <= 128): ``block_m * N * (2*s + 10*acc)`` plus
+    the cloud, about twice the dense tier's bytes per row. Checked
+    against the v5e compiler in tests/test_tpu_compile.py.
+    """
+    if implicit:
+        per_elt = 2 * itemsize + 10 * acc_itemsize
+        cloud = _vector_bytes(N, 0, 2)
+    else:
+        per_elt = 4 * itemsize + acc_itemsize
+        cloud = 0
+    return block_m * N * per_elt + _vector_bytes(block_m, N, 4) + cloud
+
+
+def resident_vmem_bytes(Mp: int, Np: int, itemsize: int = 4,
+                        acc_itemsize: int = 4, *,
+                        implicit: bool = False) -> int:
+    """Scoped VMEM one lane of the resident kernels asks Mosaic for.
+
+    Per lane (one grid step): the whole ``(Mp, Np)`` in and out tiles in
+    the storage dtype, double-buffered by the pipeline, plus two
+    ``acc_itemsize`` copies — the working tile carried through the
+    iteration loop and the rescale temporary — and the O(Mp + Np)
+    marginal, factor and column-sum vectors (the stepped kernel carries
+    the most of them: about 14 of each, in and out, double-buffered,
+    and their in-loop copies). ``implicit`` (``resident_solve_pc``)
+    drops the input tile and adds the double-buffered coordinate blocks
+    (one lane tile wide for d <= 128).
+    """
+    tiles = (2 if implicit else 4) * itemsize + 2 * acc_itemsize
+    total = Mp * Np * tiles + _vector_bytes(Mp, Np, 14)
+    if implicit:
+        total += _vector_bytes(Mp + Np, 0, 2)
+    return total
+
+
 def pick_block_m(M: int, N: int, itemsize: int = 4,
-                 acc_itemsize: int = 4) -> int:
+                 acc_itemsize: int = 4, *, implicit: bool = False) -> int:
     """Largest power-of-two row block whose VMEM working set fits the budget.
 
-    The working set per grid step is the in + out tiles in the storage dtype
-    (``itemsize`` bytes/elt, double-buffered by the pipeline) plus the fp32
-    compute copy of the tile (``acc_itemsize``): ``bm * N * (2*itemsize +
-    acc_itemsize)`` bytes. Mixed precision (bf16 storage) therefore earns a
-    larger block than fp32 at the same budget. The block is also clamped to
-    not exceed the (padded) problem height — no point padding M past the
-    next power of two.
+    The working set per grid step is ``streamed_vmem_bytes``: the in + out
+    tiles in the storage dtype (``itemsize`` bytes/elt, double-buffered by
+    the pipeline), the fp32 compute copy of the tile (``acc_itemsize``)
+    and the vector blocks. Mixed precision (bf16 storage) therefore earns
+    a larger block than fp32 at the same budget. The block is also
+    clamped to not exceed the (padded) problem height — no point padding
+    M past the next power of two. A row too wide for even the sublane
+    floor to fit gets the floor; such widths belong to ``solve_halfpass``.
     """
     sub = _sublane(itemsize)
-    bytes_per_row = N * (2 * itemsize + acc_itemsize)
     bm = 512
-    while bm > sub and (bm * bytes_per_row > _VMEM_BUDGET_BYTES
-                        or bm >= 2 * M):
+    while bm > sub and (
+            streamed_vmem_bytes(bm, N, itemsize, acc_itemsize,
+                                implicit=implicit) > VMEM_LIMIT_BYTES
+            or bm >= 2 * M):
         bm //= 2
     return max(bm, sub)
 
@@ -226,24 +280,21 @@ def resident_fits(M: int, N: int, cfg: UOTConfig, *, storage_dtype=None,
                   implicit: bool = False) -> bool:
     """Whether a (M, N) problem can run on the VMEM-resident solver tier.
 
-    The dense resident kernel (``uot_resident.resident_solve``) holds, per
-    grid step (= per lane): the in and out tiles in the storage dtype
-    (double-buffered by the pipeline), the fp32 working copy carried
-    through the iteration loop, one fp32 temporary for the rescale
-    products, and the O(M+N) factor/marginal vectors —
-    ``Mp*Np*(2*s + 2*4)`` + vector bytes against the same budget
-    ``pick_block_m`` uses for the streamed tier.
+    The shape fits when ``resident_vmem_bytes`` of its padded tile — what
+    Mosaic allocates for one lane of ``uot_resident.resident_solve`` /
+    ``resident_stepped`` — is within the same budget ``pick_block_m``
+    uses for the streamed tier, the scoped-VMEM limit every kernel
+    passes to the compiler.
 
     ``implicit=True`` is the budget of the implicit-geometry kernel
     (``resident_solve_pc``): the cost operand is O((M + N) * d)
     coordinates computed into the working tile on-chip, so there is **no
     input tile** — the M*N-sized VMEM residents shrink to the coupling
-    alone (out tile + fp32 working copy + rescale temporary:
-    ``Mp*Np*(s + 2*4)``). At fp32 that is 12 bytes/element against the
-    dense tier's 16, which is what lets ``impl='auto'`` route shapes to
-    the resident tier under an implicit geometry that the dense path must
-    stream (e.g. 1024x2048 fp32: 24 MiB implicit vs 32 MiB dense against
-    the 32 MiB budget).
+    alone (double-buffered out tile + fp32 working copy + rescale
+    temporary). At fp32 that is 16 bytes/element against the dense
+    tier's 24, which is what lets ``impl='auto'`` route shapes to the
+    resident tier under an implicit geometry that the dense path must
+    stream (e.g. 1024x2560 fp32).
 
     The test is static (shapes, dtypes, budget), so ``impl='auto'``
     dispatch is decidable at trace time and batch size does not matter:
@@ -253,15 +304,9 @@ def resident_fits(M: int, N: int, cfg: UOTConfig, *, storage_dtype=None,
     sub = _sublane(sdt.itemsize)
     Mp = M + (-M) % sub
     Np = N + (-N) % _LANE
-    budget = _VMEM_BUDGET_BYTES if budget_bytes is None else budget_bytes
-    acc = 4  # fp32 accumulator itemsize
-    # dense: in + out storage tiles + fp32 working copy + rescale temp;
-    # implicit: the input tile is computed, not loaded — out tile only
-    per_elt = (sdt.itemsize + 2 * acc if implicit
-               else 2 * sdt.itemsize + 2 * acc)
-    tile_bytes = Mp * Np * per_elt
-    vec_bytes = 4 * (Mp + Np) * acc  # a/frow/rowsum rows + b/colsum/fcol cols
-    return tile_bytes + vec_bytes <= budget
+    budget = VMEM_LIMIT_BYTES if budget_bytes is None else budget_bytes
+    return resident_vmem_bytes(Mp, Np, sdt.itemsize,
+                               implicit=implicit) <= budget
 
 
 # ``impl='auto'`` routing decisions, observable so the dispatch boundary is
@@ -680,14 +725,17 @@ def _solve_fused_batched_geometry_streamed(geom: PointCloudGeometry,
     (``uot_geometry.batched_pc_*``) — the initial coupling never exists in
     HBM; the solve's first M*N write is the already-rescaled ``A1``. From
     iteration 2 the coupling is ordinary solver state and the standard
-    streamed kernels take over, with identical blocking and identical
-    tol bookkeeping (first-iteration drift vs unit factors), so the
-    iterates match the dense-load path bit-for-bit.
+    streamed kernels take over, with identical tol bookkeeping
+    (first-iteration drift vs unit factors). Where the same row block
+    fits both paths (everywhere but near the VMEM budget, where the
+    tile-compute kernels need the smaller block) the blocking is
+    identical too, and in interpret mode the iterates match the
+    dense-load path bit-for-bit.
     """
     interpret = _interpret_default(interpret)
     M, N = geom.shape
     sdt = _storage(cfg, storage_dtype)
-    bm = block_m or pick_block_m(M, N, sdt.itemsize)
+    bm = block_m or pick_block_m(M, N, sdt.itemsize, implicit=True)
     Mp = M + (-M) % bm
     Np = N + (-N) % _LANE
     x, xn, y, yn, mv, nv = _pc_padded_operands(geom, Mp, Np)

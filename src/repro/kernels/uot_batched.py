@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.uot_fused import _safe_pow
+from repro.kernels.vmem import COMPILER_PARAMS
 
 
 def _batched_fused_iter_kernel(fcol_ref, a_ref, A_ref, out_ref, colsum_ref, *,
@@ -95,6 +96,7 @@ def batched_fused_iteration(A: jax.Array, factor_col: jax.Array,
             jax.ShapeDtypeStruct((B, 1, N), acc_dtype),
         ],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(factor_col.reshape(B, 1, N), a.reshape(B, M, 1), A)
     return out, colsum.reshape(B, N)
 
@@ -173,6 +175,7 @@ def batched_fused_iteration_frow(A: jax.Array, factor_col: jax.Array,
             jax.ShapeDtypeStruct((B, M, 1), acc_dtype),
         ],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(mask.reshape(B, 1, 1).astype(jnp.float32),
       factor_col.reshape(B, 1, N), a.reshape(B, M, 1), A)
     return out, colsum.reshape(B, N), frow.reshape(B, M)
@@ -203,6 +206,7 @@ def batched_colsum(A: jax.Array, *, block_m: int = 256,
         out_specs=pl.BlockSpec((1, 1, N), lambda b, i: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 1, N), acc_dtype),
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(A)
     return out.reshape(B, N)
 
@@ -253,6 +257,7 @@ def batched_uv_iteration(K: jax.Array, v: jax.Array, a: jax.Array, *,
             jax.ShapeDtypeStruct((B, 1, N), acc_dtype),
         ],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(v.reshape(B, 1, N), a.reshape(B, M, 1), K)
     return u.reshape(B, M), ktu.reshape(B, N)
 
@@ -282,5 +287,6 @@ def batched_materialize_coupling(K: jax.Array, u: jax.Array, v: jax.Array, *,
         out_specs=pl.BlockSpec((1, block_m, N), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, M, N), out_dtype),
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(u.reshape(B, M, 1), v.reshape(B, 1, N), K)
     return P

@@ -43,6 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.vmem import COMPILER_PARAMS
+
 
 def _safe_pow(target, sums, fi: float):
     """(target / sums) ** fi with 0-sum guard (matches core.rescale_factors)."""
@@ -112,6 +114,7 @@ def fused_iteration(A: jax.Array, factor_col: jax.Array, a: jax.Array, *,
             jax.ShapeDtypeStruct((1, N), acc_dtype),
         ],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(factor_col.reshape(1, N), a.reshape(M, 1), A)
     return out, colsum.reshape(N)
 
@@ -140,5 +143,6 @@ def colsum(A: jax.Array, *, block_m: int = 256, interpret: bool = False,
         out_specs=pl.BlockSpec((1, N), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, N), acc_dtype),
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(A)
     return out.reshape(N)

@@ -56,10 +56,11 @@ from jax.experimental import pallas as pl
 
 from repro.geometry.pointcloud import gibbs_tile
 from repro.kernels.uot_fused import _safe_pow
+from repro.kernels.vmem import COMPILER_PARAMS
 
 
 def _tile(x_ref, xn_ref, y_ref, yn_ref, mv_ref, nv_ref, i, *, block_m: int,
-          reg: float, scale: float, storage_dtype, acc_dtype):
+          reg: float, scale: float, storage_dtype, acc_dtype, barrier: bool):
     """The shared tile prologue: compute, mask, storage-roundtrip.
 
     Returns the (1, bm, N) Gibbs tile in ``acc_dtype``, bit-identical to
@@ -67,11 +68,11 @@ def _tile(x_ref, xn_ref, y_ref, yn_ref, mv_ref, nv_ref, i, *, block_m: int,
     zero-padded ``geometry.kernel(reg).astype(storage_dtype)``.
     """
     K = gibbs_tile(x_ref[...], xn_ref[...], y_ref[...], yn_ref[...],
-                   reg=reg, scale=scale)
+                   reg=reg, scale=scale, barrier=barrier)
     shape = K.shape                                   # (1, bm, N)
     rows = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + i * block_m
     cols = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
-    K = jnp.where((rows < mv_ref[0, 0]) & (cols < nv_ref[0, 0]), K, 0.0)
+    K = jnp.where((rows < mv_ref[0, 0, 0]) & (cols < nv_ref[0, 0, 0]), K, 0.0)
     if jnp.dtype(storage_dtype) != jnp.dtype(acc_dtype):
         K = K.astype(storage_dtype)
     return K.astype(acc_dtype)
@@ -84,8 +85,8 @@ def _pc_specs(B, M, N, d, block_m):
         pl.BlockSpec((1, block_m, 1), lambda b, i: (b, i, 0)),  # x sq norms
         pl.BlockSpec((1, N, d), lambda b, i: (b, 0, 0)),        # y (whole)
         pl.BlockSpec((1, 1, N), lambda b, i: (b, 0, 0)),        # y sq norms
-        pl.BlockSpec((1, 1), lambda b, i: (b, 0)),              # m_valid
-        pl.BlockSpec((1, 1), lambda b, i: (b, 0)),              # n_valid
+        pl.BlockSpec((1, 1, 1), lambda b, i: (b, 0, 0)),        # m_valid
+        pl.BlockSpec((1, 1, 1), lambda b, i: (b, 0, 0)),        # n_valid
     ]
 
 
@@ -93,16 +94,17 @@ def _pc_args(x, xn, y, yn, m_valid, n_valid):
     B, M, d = x.shape
     N = y.shape[1]
     return (x, xn.reshape(B, M, 1), y, yn.reshape(B, 1, N),
-            m_valid.astype(jnp.int32).reshape(B, 1),
-            n_valid.astype(jnp.int32).reshape(B, 1))
+            m_valid.astype(jnp.int32).reshape(B, 1, 1),
+            n_valid.astype(jnp.int32).reshape(B, 1, 1))
 
 
 def _materialize_kernel(x_ref, xn_ref, y_ref, yn_ref, mv_ref, nv_ref,
-                        out_ref, *, block_m, reg, scale, acc_dtype):
+                        out_ref, *, block_m, reg, scale, acc_dtype, barrier):
     i = pl.program_id(1)
     K = _tile(x_ref, xn_ref, y_ref, yn_ref, mv_ref, nv_ref, i,
               block_m=block_m, reg=reg, scale=scale,
-              storage_dtype=out_ref.dtype, acc_dtype=acc_dtype)
+              storage_dtype=out_ref.dtype, acc_dtype=acc_dtype,
+              barrier=barrier)
     out_ref[...] = K.astype(out_ref.dtype)
 
 
@@ -124,7 +126,8 @@ def batched_pc_materialize(x, xn, y, yn, m_valid, n_valid, *, reg: float,
     N = y.shape[1]
     assert M % block_m == 0, (M, block_m)
     kernel = functools.partial(_materialize_kernel, block_m=block_m,
-                               reg=reg, scale=scale, acc_dtype=acc_dtype)
+                               reg=reg, scale=scale, acc_dtype=acc_dtype,
+                               barrier=interpret)
     return pl.pallas_call(
         kernel,
         grid=(B, M // block_m),
@@ -132,15 +135,17 @@ def batched_pc_materialize(x, xn, y, yn, m_valid, n_valid, *, reg: float,
         out_specs=pl.BlockSpec((1, block_m, N), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, M, N), out_dtype),
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(*_pc_args(x, xn, y, yn, m_valid, n_valid))
 
 
 def _colsum_kernel(x_ref, xn_ref, y_ref, yn_ref, mv_ref, nv_ref, cs_ref, *,
-                   block_m, reg, scale, storage_dtype, acc_dtype):
+                   block_m, reg, scale, storage_dtype, acc_dtype, barrier):
     i = pl.program_id(1)
     K = _tile(x_ref, xn_ref, y_ref, yn_ref, mv_ref, nv_ref, i,
               block_m=block_m, reg=reg, scale=scale,
-              storage_dtype=storage_dtype, acc_dtype=acc_dtype)
+              storage_dtype=storage_dtype, acc_dtype=acc_dtype,
+              barrier=barrier)
 
     @pl.when(i == 0)
     def _init():
@@ -168,7 +173,7 @@ def batched_pc_colsum(x, xn, y, yn, m_valid, n_valid, *, reg: float,
     assert M % block_m == 0, (M, block_m)
     kernel = functools.partial(_colsum_kernel, block_m=block_m, reg=reg,
                                scale=scale, storage_dtype=storage_dtype,
-                               acc_dtype=acc_dtype)
+                               acc_dtype=acc_dtype, barrier=interpret)
     out = pl.pallas_call(
         kernel,
         grid=(B, M // block_m),
@@ -176,17 +181,19 @@ def batched_pc_colsum(x, xn, y, yn, m_valid, n_valid, *, reg: float,
         out_specs=pl.BlockSpec((1, 1, N), lambda b, i: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 1, N), acc_dtype),
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(*_pc_args(x, xn, y, yn, m_valid, n_valid))
     return out.reshape(B, N)
 
 
 def _first_iter_kernel(fcol_ref, a_ref, x_ref, xn_ref, y_ref, yn_ref,
                        mv_ref, nv_ref, out_ref, colsum_ref, frow_ref, *,
-                       fi, block_m, reg, scale, acc_dtype):
+                       fi, block_m, reg, scale, acc_dtype, barrier):
     i = pl.program_id(1)
     blk = _tile(x_ref, xn_ref, y_ref, yn_ref, mv_ref, nv_ref, i,
                 block_m=block_m, reg=reg, scale=scale,
-                storage_dtype=out_ref.dtype, acc_dtype=acc_dtype)
+                storage_dtype=out_ref.dtype, acc_dtype=acc_dtype,
+                barrier=barrier)
 
     # identical post-tile chain to uot_batched's fused iteration kernels —
     # the tile source is the only difference between the two paths
@@ -229,7 +236,8 @@ def batched_pc_first_iteration(factor_col, a, x, xn, y, yn, m_valid,
     N = y.shape[1]
     assert M % block_m == 0, (M, block_m)
     kernel = functools.partial(_first_iter_kernel, fi=fi, block_m=block_m,
-                               reg=reg, scale=scale, acc_dtype=acc_dtype)
+                               reg=reg, scale=scale, acc_dtype=acc_dtype,
+                               barrier=interpret)
     out, colsum, frow = pl.pallas_call(
         kernel,
         grid=(B, M // block_m),
@@ -248,6 +256,7 @@ def batched_pc_first_iteration(factor_col, a, x, xn, y, yn, m_valid,
             jax.ShapeDtypeStruct((B, M, 1), acc_dtype),
         ],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(factor_col.reshape(B, 1, N), a.reshape(B, M, 1),
       *_pc_args(x, xn, y, yn, m_valid, n_valid))
     return out, colsum.reshape(B, N), frow.reshape(B, M)

@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.vmem import COMPILER_PARAMS
+
 
 def _scale_rows_accum_cols_kernel(frow_ref, A_ref, out_ref, colsum_ref, *,
                                   acc_dtype):
@@ -69,6 +71,7 @@ def scale_rows_accum_cols(A: jax.Array, frow: jax.Array, *, block_m: int = 256,
             jax.ShapeDtypeStruct((1, N), acc_dtype),
         ],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(frow.reshape(M, 1), A)
     return out, colsum.reshape(N)
 
@@ -112,5 +115,6 @@ def scale_cols_accum_rows(A: jax.Array, fcol: jax.Array, *, block_m: int = 256,
             jax.ShapeDtypeStruct((M, 1), acc_dtype),
         ],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(fcol.reshape(1, N), A)
     return out, rowsum.reshape(M)

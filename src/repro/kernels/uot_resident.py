@@ -61,6 +61,7 @@ from jax.experimental import pallas as pl
 
 from repro.geometry.pointcloud import gibbs_tile
 from repro.kernels.uot_fused import _safe_pow
+from repro.kernels.vmem import COMPILER_PARAMS
 
 
 def _one_iteration(A, colsum, a, b, fi):
@@ -168,16 +169,17 @@ def resident_solve(A: jax.Array, a: jax.Array, b: jax.Array, *, fi: float,
         out_specs=[
             pl.BlockSpec((1, M, N), lambda i: (i, 0, 0)),   # converged tile
             pl.BlockSpec((1, 1, N), lambda i: (i, 0, 0)),   # colsum
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),         # iters
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),         # err
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),   # iters
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),   # err
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, M, N), A.dtype),
             jax.ShapeDtypeStruct((B, 1, N), acc_dtype),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), acc_dtype),
+            jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, 1), acc_dtype),
         ],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(a.reshape(B, M, 1), b.reshape(B, 1, N), A)
     return out, colsum.reshape(B, N), iters.reshape(B), err.reshape(B)
 
@@ -185,15 +187,15 @@ def resident_solve(A: jax.Array, a: jax.Array, b: jax.Array, *, fi: float,
 def _resident_pc_kernel(a_ref, b_ref, x_ref, xn_ref, y_ref, yn_ref,
                         mv_ref, nv_ref, out_ref, colsum_ref, iters_ref,
                         err_ref, *, fi: float, reg: float, scale: float,
-                        num_iters: int, tol, acc_dtype):
+                        num_iters: int, tol, acc_dtype, barrier: bool):
     # the Gibbs tile never exists in HBM: computed here, in VMEM, from the
     # O((M + N) * d) coordinate operands, then iterated on like the loaded
     # tile of _resident_solve_kernel (same loop, bit-for-bit)
     A = gibbs_tile(x_ref[...], xn_ref[...], y_ref[...], yn_ref[...],
-                   reg=reg, scale=scale)
+                   reg=reg, scale=scale, barrier=barrier)
     rows = jax.lax.broadcasted_iota(jnp.int32, A.shape, 1)
     cols = jax.lax.broadcasted_iota(jnp.int32, A.shape, 2)
-    A = jnp.where((rows < mv_ref[0, 0]) & (cols < nv_ref[0, 0]), A, 0.0)
+    A = jnp.where((rows < mv_ref[0, 0, 0]) & (cols < nv_ref[0, 0, 0]), A, 0.0)
     if jnp.dtype(out_ref.dtype) != jnp.dtype(acc_dtype):
         # round through the storage dtype so the iterate matches what the
         # dense path reads back from an HBM tile stored in that dtype
@@ -232,7 +234,7 @@ def resident_solve_pc(x, xn, y, yn, a, b, m_valid, n_valid, *, fi: float,
     N = y.shape[1]
     kernel = functools.partial(_resident_pc_kernel, fi=fi, reg=reg,
                                scale=scale, num_iters=num_iters, tol=tol,
-                               acc_dtype=acc_dtype)
+                               acc_dtype=acc_dtype, barrier=interpret)
     out, colsum, iters, err = pl.pallas_call(
         kernel,
         grid=(B,),
@@ -243,25 +245,26 @@ def resident_solve_pc(x, xn, y, yn, a, b, m_valid, n_valid, *, fi: float,
             pl.BlockSpec((1, M, 1), lambda i: (i, 0, 0)),   # x sq norms
             pl.BlockSpec((1, N, d), lambda i: (i, 0, 0)),   # y coords
             pl.BlockSpec((1, 1, N), lambda i: (i, 0, 0)),   # y sq norms
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),         # m_valid
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),         # n_valid
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),   # m_valid
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),   # n_valid
         ],
         out_specs=[
             pl.BlockSpec((1, M, N), lambda i: (i, 0, 0)),   # converged tile
             pl.BlockSpec((1, 1, N), lambda i: (i, 0, 0)),   # colsum
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),         # iters
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),         # err
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),   # iters
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),   # err
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, M, N), out_dtype),
             jax.ShapeDtypeStruct((B, 1, N), acc_dtype),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), acc_dtype),
+            jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, 1), acc_dtype),
         ],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(a.reshape(B, M, 1), b.reshape(B, 1, N), x, xn.reshape(B, M, 1),
-      y, yn.reshape(B, 1, N), m_valid.astype(jnp.int32).reshape(B, 1),
-      n_valid.astype(jnp.int32).reshape(B, 1))
+      y, yn.reshape(B, 1, N), m_valid.astype(jnp.int32).reshape(B, 1, 1),
+      n_valid.astype(jnp.int32).reshape(B, 1, 1))
     return out, colsum.reshape(B, N), iters.reshape(B), err.reshape(B)
 
 
@@ -345,9 +348,9 @@ def _resident_stepped_kernel(active_ref, conv_ref, iters_ref, a_ref, b_ref,
     b = b_ref[...].astype(acc_dtype)
     colsum = cs_ref[...].astype(acc_dtype)        # carried, (1, 1, Np)
     prev = frow_ref[...].astype(acc_dtype)        # carried, (1, Mp, 1)
-    live = jnp.logical_and(active_ref[0, 0] > 0, conv_ref[0, 0] == 0)
-    conv0 = conv_ref[0, 0] > 0
-    it0 = iters_ref[0, 0]
+    live = jnp.logical_and(active_ref[0, 0, 0] > 0, conv_ref[0, 0, 0] == 0)
+    conv0 = conv_ref[0, 0, 0] > 0
+    it0 = iters_ref[0, 0, 0]
 
     # The streamed stepped path updates a lane iff it is active, not yet
     # converged, and below the iteration cap — here that gate IS the loop
@@ -414,9 +417,9 @@ def resident_stepped(A: jax.Array, colsum: jax.Array, frow: jax.Array,
         kernel,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),         # active
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),         # converged
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),         # iters
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),   # active
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),   # converged
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),   # iters
             pl.BlockSpec((1, M, 1), lambda i: (i, 0, 0)),   # a
             pl.BlockSpec((1, 1, N), lambda i: (i, 0, 0)),   # b
             pl.BlockSpec((1, 1, N), lambda i: (i, 0, 0)),   # carried colsum
@@ -427,20 +430,21 @@ def resident_stepped(A: jax.Array, colsum: jax.Array, frow: jax.Array,
             pl.BlockSpec((1, M, N), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, 1, N), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, M, 1), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, M, N), A.dtype),
             jax.ShapeDtypeStruct((B, 1, N), acc_dtype),
             jax.ShapeDtypeStruct((B, M, 1), acc_dtype),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(active.astype(jnp.float32).reshape(B, 1),
-      converged.astype(jnp.float32).reshape(B, 1),
-      iters.astype(jnp.int32).reshape(B, 1),
+        compiler_params=COMPILER_PARAMS,
+    )(active.astype(jnp.float32).reshape(B, 1, 1),
+      converged.astype(jnp.float32).reshape(B, 1, 1),
+      iters.astype(jnp.int32).reshape(B, 1, 1),
       a.reshape(B, M, 1), b.reshape(B, 1, N),
       colsum.reshape(B, 1, N), frow.reshape(B, M, 1), A)
     return (out, cs.reshape(B, N), fr.reshape(B, M), it.reshape(B),

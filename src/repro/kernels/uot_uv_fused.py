@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.uot_fused import _safe_pow
+from repro.kernels.vmem import COMPILER_PARAMS
 
 
 def _uv_iter_kernel(v_ref, a_ref, K_ref, u_ref, ktu_ref, *, fi: float,
@@ -75,6 +76,7 @@ def uv_iteration(K: jax.Array, v: jax.Array, a: jax.Array, *, fi: float,
             jax.ShapeDtypeStruct((1, N), acc_dtype),
         ],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(v.reshape(1, N), a.reshape(M, 1), K)
     return u.reshape(M), ktu.reshape(N)
 
@@ -104,5 +106,6 @@ def materialize_coupling(K: jax.Array, u: jax.Array, v: jax.Array, *,
         out_specs=pl.BlockSpec((block_m, N), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
     )(u.reshape(M, 1), v.reshape(1, N), K)
     return P

@@ -20,9 +20,23 @@ import dataclasses
 import json
 import re
 
-PEAK_FLOPS = 197e12       # bf16 / chip
-HBM_BW = 819e9            # bytes/s / chip
-ICI_BW = 50e9             # bytes/s / link
+# Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB
+# HBM at 819 GB/s, 1,600 Gbit/s of interconnect over four links). A device
+# that is not in this table has no roofline: ``peaks`` returns None.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+# The dry-run target (a described v5e topology).
+PEAK_FLOPS = PEAKS["TPU v5 lite"]["flops"]    # bf16 / chip
+HBM_BW = PEAKS["TPU v5 lite"]["hbm_bw"]       # bytes/s / chip
+ICI_BW = PEAKS["TPU v5 lite"]["ici_bw"]       # bytes/s / link
+
+
+def peaks(device_kind: str | None) -> dict | None:
+    """The published peaks of ``device_kind``, or None when unknown."""
+    return PEAKS.get(device_kind)
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

@@ -11,7 +11,8 @@ cell in one process; this module makes those measurements *durable* and
   wall-clock comparison is meaningless, and silently mixing fingerprints
   is how perf data rots. Combined with ``repro.obs.traffic``'s modeled
   byte formulas each cell yields **achieved GB/s** and a **measured**
-  roofline fraction (``achieved / launch.roofline.HBM_BW``) next to the
+  roofline fraction (achieved over the device's HBM peak in
+  ``launch.roofline.PEAKS``, None off that table) next to the
   modeled one — the paper's Fig-11 bandwidth story, finally measured
   instead of assumed.
 * ``MeasuredDispatch`` — the advisor ``kernels/ops.py`` consults from
@@ -40,7 +41,7 @@ import json
 import pathlib
 import platform
 
-from repro.launch.roofline import HBM_BW
+from repro.launch.roofline import peaks
 from repro.obs.traffic import chunk_bytes as _chunk_bytes
 from repro.obs.traffic import solve_bytes as _solve_bytes
 from repro.obs.profile import parse_cell_key
@@ -208,8 +209,11 @@ class MeasurementStore:
         """Per-cell achieved bandwidth from measured time over modeled
         bytes: ``{key: {median_us, modeled_bytes, achieved_gbps,
         measured_roofline_fraction}}``. The fraction is against the
-        datasheet ``HBM_BW`` — honest only on real HBM; on CPU hosts it
-        reports how far host execution sits from TPU bandwidth."""
+        HBM peak of the fingerprint's ``device_kind`` in
+        ``launch.roofline.PEAKS``; a device that is not in that table (a
+        CPU host, an unlisted chip) has none: the fraction is None, "not
+        measured"."""
+        peak = peaks(self.fingerprint.get("device_kind"))
         out = {}
         for key, cell in self.cells.items():
             us = cell.get("median_us")
@@ -221,7 +225,9 @@ class MeasurementStore:
             gbps = nbytes / (us * 1e-6) / 1e9
             out[key] = {"median_us": us, "modeled_bytes": nbytes,
                         "achieved_gbps": gbps,
-                        "measured_roofline_fraction": gbps / (HBM_BW / 1e9)}
+                        "measured_roofline_fraction": (
+                            None if peak is None
+                            else gbps / (peak["hbm_bw"] / 1e9))}
         return out
 
 

@@ -16,7 +16,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -72,11 +71,11 @@ def pipeline_apply(mesh: Mesh, axis: str, stage_fn, stage_params, x_mb):
         return jax.lax.psum(ys, axis)
 
     n_axes = x_mb.ndim
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params,
                                is_leaf=lambda x: hasattr(x, "shape")),
                   P(*([None] * n_axes))),
         out_specs=P(*([None] * n_axes)),
-        check_rep=False)
+        check_vma=False)
     return fn(stage_params, x_mb)

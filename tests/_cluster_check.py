@@ -58,8 +58,8 @@ def check_sharded_advance_bit_identity(mesh, cfg):
         st = ops.solve_fused_stepped(st, 4, cfg, impl="jnp")
         cs = cluster_stepped(cs, 4, cfg, mesh=mesh, impl="jnp")
         cs_loop = cluster_stepped(cs_loop, 4, cfg, mesh=None, impl="jnp")
-    assert np.array_equal(np.asarray(cs.lanes.P[5, 0]), np.asarray(st.P[0]))
-    assert int(cs.lanes.iters[5, 0]) == int(st.iters[0])
+    assert np.array_equal(np.asarray(cs.lanes.P)[5, 0], np.asarray(st.P[0]))
+    assert int(np.asarray(cs.lanes.iters)[5, 0]) == int(st.iters[0])
     for a_leaf, b_leaf in zip(jax.tree_util.tree_leaves(cs),
                               jax.tree_util.tree_leaves(cs_loop)):
         assert np.array_equal(np.asarray(a_leaf), np.asarray(b_leaf))

@@ -273,16 +273,16 @@ class TestDispatchExpansion:
     CFG = UOTConfig(reg=0.05, reg_m=1.0, num_iters=2)
 
     def test_implicit_budget_is_wider(self):
-        # fp32: dense 16 B/elt vs implicit 12 B/elt against the same
-        # budget — 1024x2048 is exactly the gap
-        assert not ops.resident_fits(1024, 2048, self.CFG)
-        assert ops.resident_fits(1024, 2048, self.CFG, implicit=True)
+        # fp32: dense 24 B/elt vs implicit 16 B/elt against the same
+        # budget — 1024x2560 is in the gap
+        assert not ops.resident_fits(1024, 2560, self.CFG)
+        assert ops.resident_fits(1024, 2560, self.CFG, implicit=True)
         # both agree on clearly-fitting and clearly-over shapes
         assert ops.resident_fits(256, 384, self.CFG, implicit=True)
         assert not ops.resident_fits(4096, 4096, self.CFG, implicit=True)
 
     def test_auto_routes_implicit_to_resident_where_dense_streams(self):
-        M, N = 1024, 2048
+        M, N = 1024, 2560
         rng = np.random.default_rng(5)
         x = rng.uniform(0, 1, (M, 3)).astype(np.float32)
         y = rng.uniform(0, 1, (N, 3)).astype(np.float32)

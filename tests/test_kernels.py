@@ -14,6 +14,7 @@ from repro.kernels.uot_fused import fused_iteration, colsum
 from repro.kernels.uot_halfpass import (
     scale_rows_accum_cols, scale_cols_accum_rows)
 from repro.kernels.uot_uv_fused import uv_iteration, materialize_coupling
+from repro.kernels.vmem import VMEM_LIMIT_BYTES
 
 
 def rand(shape, seed=0, dtype=jnp.float32, lo=0.1, hi=2.0):
@@ -155,5 +156,11 @@ class TestAssembledSolvers:
 
     def test_block_autotune_bounds(self):
         assert ops.pick_block_m(10_000, 512) == 512
-        bm = ops.pick_block_m(100_000, 1_000_000)
-        assert bm >= 8 and 2 * bm * 1_000_000 * 4 <= 2 * ops._VMEM_BUDGET_BYTES
+        assert ops.streamed_vmem_bytes(512, 512) <= VMEM_LIMIT_BYTES
+        # the paper's largest size: the block shrinks until it fits
+        bm = ops.pick_block_m(20480, 20480)
+        assert bm >= 8 and ops.streamed_vmem_bytes(
+            bm, 20480) <= VMEM_LIMIT_BYTES < ops.streamed_vmem_bytes(
+                2 * bm, 20480)
+        # a row too wide for any block gets the sublane floor
+        assert ops.pick_block_m(100_000, 1_000_000) == 8

@@ -208,7 +208,16 @@ class TestMeasurementStore:
         assert ach[key]["modeled_bytes"] == nbytes
         assert ach[key]["achieved_gbps"] == \
             pytest.approx(nbytes / 100e-6 / 1e9)
-        assert 0 < ach[key]["measured_roofline_fraction"] < float("inf")
+        # no device kind in the fingerprint: no peak, no roofline share
+        assert ach[key]["measured_roofline_fraction"] is None
+        v5e = MeasurementStore(fingerprint={"id": "t",
+                                            "device_kind": "TPU v5 lite"})
+        v5e.record(key, 100.0, count=3)
+        assert v5e.achieved()[key]["measured_roofline_fraction"] == \
+            pytest.approx(nbytes / 100e-6 / 819e9)
+        cpu = MeasurementStore(fingerprint={"id": "t", "device_kind": "cpu"})
+        cpu.record(key, 100.0, count=3)
+        assert cpu.achieved()[key]["measured_roofline_fraction"] is None
 
 
 # ---- measurement-driven dispatch -------------------------------------------

@@ -195,14 +195,14 @@ class TestDispatch:
     CFG = UOTConfig(reg=0.1, reg_m=1.0, num_iters=2)
 
     def test_fits_boundary_exact(self):
-        # fp32 model: Mp*Np*(2*4 + 2*4) + vectors <= 32 MiB. At Np = 1024
-        # the largest fitting Mp is 2048 minus the vector overhead rows.
-        assert ops.resident_fits(2040, 1024, self.CFG)
-        assert not ops.resident_fits(2056, 1024, self.CFG)
-        # bf16 storage earns more rows at the same budget (12 B/elt)
-        assert ops.resident_fits(2720, 1024, self.CFG,
+        # fp32 model: Mp*Np*(4*4 + 2*4) + vectors <= 64 MiB. At Np = 1024
+        # the largest fitting Mp is 2730 minus the vector overhead rows.
+        assert ops.resident_fits(2096, 1024, self.CFG)
+        assert not ops.resident_fits(2104, 1024, self.CFG)
+        # bf16 storage earns more rows at the same budget (16 B/elt)
+        assert ops.resident_fits(2816, 1024, self.CFG,
                                  storage_dtype=jnp.bfloat16)
-        assert not ops.resident_fits(2736, 1024, self.CFG,
+        assert not ops.resident_fits(2832, 1024, self.CFG,
                                      storage_dtype=jnp.bfloat16)
         # the serving bucket shapes the tier was built for are way inside
         assert ops.resident_fits(256, 384, self.CFG)
@@ -212,7 +212,7 @@ class TestDispatch:
     def test_auto_routes_over_budget_problem_to_streamed(self):
         """A problem just over budget must dispatch streamed — and still
         produce the right answer."""
-        M, N = 2056, 1024  # just over the fp32 boundary above
+        M, N = 2104, 1024  # just over the fp32 boundary above
         rng = np.random.default_rng(7)
         K = jnp.asarray(rng.uniform(0.1, 1.0, (1, M, N)), jnp.float32)
         a = jnp.asarray(rng.uniform(0.5, 1.5, (1, M)), jnp.float32)
@@ -245,7 +245,7 @@ class TestDispatch:
         """solve_fused(impl='auto') must honor cfg.tol on BOTH sides of
         the dispatch boundary — the streamed fallback goes through the
         per-lane early-exit path, not the legacy fixed-iteration loop."""
-        M, N = 2056, 1024
+        M, N = 2104, 1024
         rng = np.random.default_rng(11)
         K = jnp.asarray(rng.uniform(0.1, 1.0, (M, N)), jnp.float32)
         a = jnp.asarray(rng.uniform(0.5, 1.5, M), jnp.float32)
