@@ -124,7 +124,9 @@ of ``dispatch_observer()`` — it times every routed solve/chunk launch
 parameters, so ``repro.obs.measure`` divides each cell's modeled bytes
 by its measured seconds into achieved GB/s and a measured roofline
 fraction, and ``dispatch_advisor()`` feeds those measurements back into
-the 'auto' routing above.
+the 'auto' routing above. The single-device serving round no longer
+installs it: its phases are profiler annotations, and a profiler trace
+gives each chunk's device time by op without a sync.
 
 bf16 storage on the resident tier upcasts once at load and downcasts once
 at store, so the per-iteration bf16 rounding of the streamed path
@@ -382,7 +384,10 @@ def dispatch_observer(cb):
 # measured seconds divide modeled bytes directly). Timing a launch forces
 # a ``block_until_ready`` sync, so nothing is timed unless a profiler is
 # actually installed — and ``launch_profiler`` refuses disabled/null
-# profilers outright, keeping the ``obs=False`` path sync-free.
+# profilers outright, keeping the ``obs=False`` path sync-free. The
+# ``UOTScheduler`` round does not install it (its phases are profiler
+# annotations, and the trace times its chunks on the device); the cluster
+# scheduler's sync step mode still does.
 _LAUNCH_PROF: contextvars.ContextVar[tuple] = contextvars.ContextVar(
     "uot_launch_profilers", default=())
 

@@ -37,16 +37,22 @@ The accountant's bytes are *modeled*; two further members carry the
 
 * ``phases`` — ``PhaseTimer``: scheduler round phases under
   ``profile.phase.<name>`` (total) and ``...<name>.self`` (exclusive of
-  nested phases). Names: ``serve.{evict,admit,chunk,poll}`` and
-  ``cluster.{prep,evict,admit,gang,chunk,poll}``, in seconds.
-* ``profile`` — ``KernelProfiler``: every dispatched solve/chunk timed
-  per measurement cell ``kernel|MxN|s<itemsize>|impl|source|L|T`` (the
+  nested phases), in seconds. Names: ``serve.{evict,evict.read,admit,
+  admit.launch,chunk,upkeep,poll,points}`` and
+  ``cluster.{prep,evict,admit,gang,chunk,poll}``. Each phase is also a
+  ``jax.profiler.TraceAnnotation`` of its name over the same interval,
+  so a profiler trace charges device time and idle time to the phases
+  on the device's own clock.
+* ``profile`` — ``KernelProfiler``: solve/chunk launches timed per
+  measurement cell ``kernel|MxN|s<itemsize>|impl|source|L|T`` (the
   traffic formulas' own parameters), first-call (trace+compile) under
   ``profile.compile.<cell>`` split from steady-state execute under
   ``profile.kernel.<cell>``. The hook is installed around launches via
   ``ops.launch_profiler`` and forces a device sync per timed launch —
   which is why ``enabled=False`` swaps in null twins that install
-  nothing.
+  nothing. Only the cluster scheduler's sync step mode installs it: the
+  ``UOTScheduler`` round no longer times launches (a profiler trace
+  gives its chunks' device time), so its profiler stays empty.
 
 ``measure.MeasurementStore`` persists a profiler's cells as
 fingerprint-stamped JSON (schema in its docstring); dividing each
@@ -184,7 +190,7 @@ class Observability:
         self.flight = NullFlightRecorder()
         self.exporter = NullExporter()
         if enabled:
-            self.tracer = SpanTracer(clock=clock)
+            self.tracer = SpanTracer(clock=clock, registry=self.registry)
             self.traffic = TrafficAccountant(
                 parent=parent.traffic if parent is not None else None)
             # wall-clock instruments (see "Measured performance" above):

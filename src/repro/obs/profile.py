@@ -9,12 +9,17 @@ beside the modeled ones.
 Two instruments, both feeding ``profile.*`` registry histograms:
 
 * ``PhaseTimer`` — scoped timers for the scheduler round phases
-  (admission prep / device chunk / eviction / poll, in both the
-  ``serve`` and ``cluster`` step loops). Phases nest: each phase records
+  (eviction / its per-lane read-back / admission / its launches /
+  device chunk / upkeep / poll in the ``serve`` loop, and the
+  ``cluster`` step loop's phases). Phases nest: each phase records
   its **total** wall time under ``profile.phase.<name>`` and its
   **exclusive** time (total minus enclosed child phases) under
   ``profile.phase.<name>.self``, so a round's breakdown sums correctly
-  even when one phase wraps another.
+  even when one phase wraps another. Each phase is also a
+  ``jax.profiler.TraceAnnotation`` of the same name over the same
+  interval, so a profiler trace shows the phases on the device
+  timeline's clock (and charges device idle time to them); outside a
+  trace an annotation costs about a microsecond.
 * ``KernelProfiler`` — per-launch timing of every dispatched solve /
   chunk, keyed by the **measurement cell**
   ``(kernel, MxN shape, storage itemsize, impl tier, cost source,
@@ -29,11 +34,15 @@ Two instruments, both feeding ``profile.*`` registry histograms:
   the hook via ``ops.launch_profiler(profiler)`` — the launch-timing
   twin of ``ops.dispatch_observer`` — and forces a device sync per
   profiled launch, which is why the null twins exist: under
-  ``obs=False`` nothing is installed and no sync happens.
+  ``obs=False`` nothing is installed and no sync happens. The
+  single-device ``UOTScheduler`` no longer installs it (its chunk
+  device time is read from a profiler trace instead); the cluster
+  scheduler's sync step mode still does.
 
 Clocks: phase/launch timing uses ``time.perf_counter`` by default even
 when the owning scheduler runs on a simulated clock — kernel cost is a
-host wall-clock fact, not a DES fact. Tests inject a fake ``clock=``.
+host wall-clock fact, not a DES fact. Tests inject a fake ``clock=``;
+the annotations always run on the profiler's own clock.
 """
 from __future__ import annotations
 
@@ -42,6 +51,8 @@ import contextlib
 import threading
 import time
 from typing import Callable
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["PhaseTimer", "NullPhaseTimer", "KernelProfiler",
            "NullKernelProfiler", "cell_key", "parse_cell_key"]
@@ -68,8 +79,9 @@ class PhaseTimer:
 
     ``with phases.phase("serve.chunk"): ...`` observes the elapsed
     seconds into ``profile.phase.serve.chunk`` and the exclusive
-    (children-subtracted) seconds into ``...serve.chunk.self``. The
-    phase stack is thread-local: concurrent step loops in different
+    (children-subtracted) seconds into ``...serve.chunk.self``, and
+    opens a ``TraceAnnotation("serve.chunk")`` over the same interval.
+    The phase stack is thread-local: concurrent step loops in different
     threads do not see each other's frames.
     """
 
@@ -91,13 +103,16 @@ class PhaseTimer:
     @contextlib.contextmanager
     def phase(self, name: str):
         stack = self._stack()
+        annotation = TraceAnnotation(name)
+        annotation.__enter__()
         frame = [self.clock(), 0.0]   # [start, accumulated child total]
         stack.append(frame)
         try:
             yield
         finally:
-            stack.pop()
             total = self.clock() - frame[0]
+            annotation.__exit__(None, None, None)
+            stack.pop()
             if stack:
                 stack[-1][1] += total
             self.registry.histogram(f"{self.prefix}.{name}").observe(total)
@@ -106,7 +121,8 @@ class PhaseTimer:
 
 
 class NullPhaseTimer:
-    """``obs=False`` twin: ``phase()`` is a free nullcontext."""
+    """``obs=False`` twin: ``phase()`` is a free nullcontext (no timer,
+    no annotation)."""
 
     enabled = False
 

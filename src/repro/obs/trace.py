@@ -43,6 +43,12 @@ carry no request lifecycle, so ``rids()`` and ``check_complete`` skip
 negative rids — an alert never shows up as a lost span. ``span(-1)``
 still returns them for inspection.
 
+The event list is bounded: past ``max_events`` the oldest events are
+dropped (the oldest eighth at once, so trimming stays cheap per event)
+and counted in the registry's ``tracer.dropped_events`` counter (the
+owning bundle's registry, or the tracer's own). A rid whose ``complete``
+event fell off reads as missing in ``check_complete``.
+
 Export is JSONL (one event per line, ``write_jsonl``/``load_jsonl``
 round-trip exactly) and ``render_timeline`` draws a text timeline for
 humans. ``NullTracer`` is the disabled twin: same surface, ``emit`` is a
@@ -54,23 +60,38 @@ import json
 import time
 from typing import Callable, Iterable
 
+from repro.obs.registry import MetricsRegistry
+
 TERMINAL_STATUSES = ("ok", "retried_ok", "timed_out", "failed", "rejected",
                      "lost")
+DROPPED_COUNTER = "tracer.dropped_events"
 
 
 class SpanTracer:
-    """Append-only per-request event recorder (see module docstring)."""
+    """Append-only per-request event recorder, bounded at ``max_events``
+    (see module docstring)."""
 
     enabled = True
 
-    def __init__(self, clock: Callable[[], float] = time.monotonic):
+    def __init__(self, clock: Callable[[], float] = time.monotonic, *,
+                 max_events: int = 1_000_000, registry=None):
         self.clock = clock
+        self.max_events = max_events
+        self.registry = registry if registry is not None else MetricsRegistry()
         self.events: list[dict] = []
 
     def emit(self, rid: int, event: str, **fields) -> None:
         e = {"rid": rid, "event": event, "t": self.clock()}
         e.update(fields)
         self.events.append(e)
+        if len(self.events) > self.max_events:
+            self._trim()
+
+    def _trim(self) -> None:
+        excess = (len(self.events) - self.max_events
+                  + self.max_events // 8)
+        del self.events[:excess]
+        self.registry.counter(DROPPED_COUNTER).inc(excess)
 
     def clear(self) -> None:
         self.events.clear()

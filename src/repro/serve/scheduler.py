@@ -790,7 +790,10 @@ class UOTScheduler:
         # jitted helper — reusing them at admission is what keeps the
         # batched device materialization bit-identical to a per-request
         # geometry's kernel() (see repro.geometry.pointcloud rule 1)
-        g = PointCloudGeometry.from_points(x, y, scale=scale)
+        with self.obs.phases.phase("serve.points"):
+            g = PointCloudGeometry.from_points(x, y, scale=scale)
+            coords = dict(x=np.asarray(g.x), y=np.asarray(g.y),
+                          xn=np.asarray(g.xn), yn=np.asarray(g.yn))
         M, N = g.shape
         a = np.asarray(a)
         b = np.asarray(b)
@@ -815,8 +818,7 @@ class UOTScheduler:
         req = ScheduledRequest(
             rid=rid, K=None, a=a, b=b, shape=(M, N), bucket=bucket,
             arrival=now, deadline=deadline, priority=priority,
-            x=np.asarray(g.x), y=np.asarray(g.y), xn=np.asarray(g.xn),
-            yn=np.asarray(g.yn), scale=float(scale), fault=fault)
+            scale=float(scale), fault=fault, **coords)
         self._feasibility_gate(req, now, rid)   # may raise / degrade
         self._queue.append(req)
         self.obs.tracer.emit(rid, "queue", depth=len(self._queue),
@@ -857,13 +859,21 @@ class UOTScheduler:
     # ---- the scheduling loop ---------------------------------------------
 
     def step(self) -> dict[int, np.ndarray]:
-        """One scheduling round: evict -> admit -> advance one chunk.
+        """One scheduling round: evict -> admit -> advance one chunk ->
+        upkeep (occupancy snapshot, operational plane).
 
         Returns the requests completed by this round, ``{rid: P (M, N)}``
         as host numpy arrays (also retained for ``poll``, padding-free
         copies). Eviction happens *before* admission
         so freshly-freed lanes are immediately reusable — the continuous
         part of continuous batching.
+
+        Each phase is a ``PhaseTimer`` phase (``serve.evict`` with a
+        ``serve.evict.read`` per evicted lane, ``serve.admit`` with a
+        ``serve.admit.launch`` per pool update, ``serve.chunk``,
+        ``serve.upkeep``), and so a profiler annotation. Chunk launches
+        are not synced or timed here: the next round's flag reads wait
+        for them, and a profiler trace gives their device time by op.
         """
         if self.fault_injector is not None:
             self.fault_injector.on_step(self)
@@ -886,12 +896,9 @@ class UOTScheduler:
             for bucket, pool in list(self._pools.items()):
                 if pool.requests:
                     pool.idle_steps = 0
-                    # launch_profiler times the chunk to completion (a
-                    # no-op install under obs=False); the advisor makes
-                    # impl='auto' routing measurement-driven when a
-                    # MeasurementStore was passed
+                    # the advisor makes impl='auto' routing measurement-
+                    # driven when a MeasurementStore was passed
                     with ops.dispatch_counters() as counters, \
-                            ops.launch_profiler(self.obs.profile), \
                             (ops.dispatch_advisor(self._advisor)
                              if self._advisor is not None
                              else contextlib.nullcontext()):
@@ -908,8 +915,9 @@ class UOTScheduler:
                             and pool.idle_steps > self.pool_idle_ttl):
                         del self._pools[bucket]
         self._steps += 1
-        self._snapshot_occupancy()
-        self._operational_round()
+        with ph.phase("serve.upkeep"):
+            self._snapshot_occupancy()
+            self._operational_round()
         return completed
 
     def _on_alert(self, alert) -> None:
@@ -1057,14 +1065,16 @@ class UOTScheduler:
                     # the whole pool, no per-(lane, shape) compile jitter,
                     # and a copy so the retained result doesn't pin the
                     # padded lane buffer
-                    P = np.asarray(pool.state.P[lane])[:M, :N].copy()
-                    # second line of defense, O(M*N) on the one evicted
-                    # slice only: poison that lands AFTER the convergence
-                    # latch froze the lane (e.g. injected state
-                    # corruption) never passes through the detector's
-                    # frow/colsum window — catch it on the way out
-                    if not np.all(np.isfinite(P)):
-                        P = None
+                    with self.obs.phases.phase("serve.evict.read"):
+                        P = np.asarray(pool.state.P[lane])[:M, :N].copy()
+                        # second line of defense, O(M*N) on the one
+                        # evicted slice only: poison that lands AFTER the
+                        # convergence latch froze the lane (e.g. injected
+                        # state corruption) never passes through the
+                        # detector's frow/colsum window — catch it on the
+                        # way out
+                        if not np.all(np.isfinite(P)):
+                            P = None
                 n_iters = int(iters[lane])
                 tr.emit(req.rid, "evict", lane=lane, device=-1,
                         iters=n_iters, converged=bool(conv[lane]),
@@ -1326,9 +1336,10 @@ class UOTScheduler:
         self.obs.traffic.charge_admission(
             route="lane", M=Mb, N=Nb, s=4, source="dense",
             count=len(placed))
-        pool.state = ops.lane_admit(
-            pool.state, jnp.asarray(lanes), jnp.asarray(Kp),
-            jnp.asarray(ap), jnp.asarray(bp))
+        with self.obs.phases.phase("serve.admit.launch"):
+            pool.state = ops.lane_admit(
+                pool.state, jnp.asarray(lanes), jnp.asarray(Kp),
+                jnp.asarray(ap), jnp.asarray(bp))
 
     def _admit_points(self, bucket, placed, d: int, scale: float) -> None:
         """Admit a round's point-cloud requests: transfer coordinates,
@@ -1356,16 +1367,17 @@ class UOTScheduler:
             ap[j, :M] = req.a
             bp[j, :N] = req.b
             lanes[j] = lane
-        g = PointCloudGeometry(
-            x=jnp.asarray(xs), y=jnp.asarray(ys), xn=jnp.asarray(xns),
-            yn=jnp.asarray(yns), m_valid=jnp.asarray(mv),
-            n_valid=jnp.asarray(nv), scale=scale)
         self.obs.traffic.charge_admission(
             route="lane", M=Mb, N=Nb, s=4, source="implicit", d=d,
             count=len(placed))
-        pool.state = ops.lane_admit(
-            pool.state, jnp.asarray(lanes), g.kernel(self.cfg.reg),
-            jnp.asarray(ap), jnp.asarray(bp))
+        with self.obs.phases.phase("serve.admit.launch"):
+            g = PointCloudGeometry(
+                x=jnp.asarray(xs), y=jnp.asarray(ys), xn=jnp.asarray(xns),
+                yn=jnp.asarray(yns), m_valid=jnp.asarray(mv),
+                n_valid=jnp.asarray(nv), scale=scale)
+            pool.state = ops.lane_admit(
+                pool.state, jnp.asarray(lanes), g.kernel(self.cfg.reg),
+                jnp.asarray(ap), jnp.asarray(bp))
 
     def _snapshot_occupancy(self) -> None:
         occ = {str(b): p.occupancy for b, p in self._pools.items()}

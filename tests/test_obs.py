@@ -132,6 +132,25 @@ class TestTracer:
         timeline = tr.render_timeline()
         assert isinstance(timeline, str) and timeline
 
+    def test_bounded_events_drop_the_oldest_and_count_them(self):
+        reg = obslib.MetricsRegistry()
+        tr = obslib.SpanTracer(clock=lambda: 0.0, max_events=16,
+                               registry=reg)
+        for rid in range(100):
+            tr.emit(rid, "submit")
+        assert len(tr.events) <= 16
+        assert reg.counter("tracer.dropped_events").value == \
+            100 - len(tr.events)
+        # what is kept is the newest events, in order
+        assert [e["rid"] for e in tr.events] == list(
+            range(100 - len(tr.events), 100))
+        # a bundle's tracer counts its drops in the bundle's registry,
+        # and the default bound drops nothing a test produces
+        obs = bundle()
+        assert obs.tracer.registry is obs.registry
+        assert obs.tracer.max_events >= 10 ** 6
+        assert obs.registry.get("tracer.dropped_events") is None
+
     def test_disabled_bundle_swaps_in_null_twins(self):
         obs = bundle(enabled=False)
         obs.tracer.emit(0, "submit")

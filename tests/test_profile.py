@@ -7,6 +7,7 @@ claiming streamed is faster must actually flip a resident-eligible
 solve to the streamed tier, and an empty store must leave the static
 ``resident_fits`` verdict untouched.
 """
+import collections
 import json
 
 import numpy as np
@@ -365,21 +366,23 @@ class TestSchedulerProfiling:
         return sched
 
     def test_serve_phases_and_cells(self):
+        # the serving round no longer syncs each chunk launch to time it
+        # (a profiler trace gives chunk device time): phases record,
+        # kernel cells must not
         sched = self._drive(UOTScheduler(
             self.CFG_RUN, lanes_per_pool=2, chunk_iters=5, interpret=True,
             obs=bundle()))
-        cells = sched.obs.profile.cells()
-        assert cells and all(k.startswith("chunk|") for k in cells)
+        assert sched.obs.profile.enabled
+        assert sched.obs.profile.cells() == {}
         reg = sched.obs.registry.dump()["histograms"]
-        for name in ("serve.evict", "serve.admit", "serve.chunk",
+        assert not any(k.startswith(("profile.kernel.", "profile.compile."))
+                       for k in reg)
+        for name in ("serve.evict", "serve.evict.read", "serve.admit",
+                     "serve.admit.launch", "serve.chunk", "serve.upkeep",
                      "serve.poll"):
             full = f"profile.phase.{name}"
             assert reg[full]["count"] > 0, full
             assert f"{full}.self" in reg
-        # ingest -> the store now predicts this scheduler's chunk cost
-        store = MeasurementStore()
-        assert store.ingest(sched.obs.profile) > 0
-        assert measured_seconds_per_iter(store) > 0
 
     def test_cluster_phases_and_cells(self):
         sched = self._drive(ClusterScheduler(
@@ -403,17 +406,68 @@ class TestSchedulerProfiling:
 
     def test_cells_roll_up_to_global(self):
         # default (chained) bundles feed the process-global profiler's
-        # cells, so OBS_<suite>.json dumps carry measured cells
+        # cells, so OBS_<suite>.json dumps carry measured cells; the
+        # cluster scheduler's sync mode is what still times launches
         obslib.reset_global()
-        sched = self._drive(UOTScheduler(
-            self.CFG_RUN, lanes_per_pool=2, chunk_iters=5, interpret=True))
+        sched = self._drive(ClusterScheduler(
+            self.CFG_RUN, num_devices=1, lanes_per_device=2, chunk_iters=5,
+            interpret=True, step_mode="sync"))
         try:
             local = sched.obs.profile.cells()
             global_cells = obslib.get_global().profile.cells()
-            assert set(local) <= set(global_cells)
-            assert global_cells
+            assert local and set(local) <= set(global_cells)
+            # ingest -> the store now predicts this scheduler's chunk cost
+            store = MeasurementStore()
+            assert store.ingest(obslib.get_global().profile) > 0
+            assert measured_seconds_per_iter(store) > 0
         finally:
             obslib.reset_global()
+
+    def test_round_phases_nest_in_a_profiler_trace(self, tmp_path):
+        # the phases are profiler annotations: a trace taken around the
+        # rounds holds every phase, each inside the span it belongs to
+        import jax
+        from bench import trace as btrace
+        sched = UOTScheduler(self.CFG_RUN, lanes_per_pool=2, chunk_iters=5,
+                             interpret=True, obs=bundle())
+        rng = np.random.default_rng(3)
+        jax.profiler.start_trace(str(tmp_path),
+                                 profiler_options=btrace.options())
+        try:
+            for i in range(2):
+                with jax.profiler.TraceAnnotation("serve.submit"):
+                    sched.submit(*_problem(12, 16, seed=i))
+                _, a, b = _problem(10, 14, seed=10 + i)
+                with jax.profiler.TraceAnnotation("serve.submit"):
+                    sched.submit_points(
+                        rng.uniform(size=(10, 3)).astype(np.float32),
+                        rng.uniform(size=(14, 3)).astype(np.float32), a, b,
+                        scale=3.0)
+            for _ in range(12):
+                with jax.profiler.TraceAnnotation("serve.step"):
+                    sched.step()
+        finally:
+            jax.profiler.stop_trace()
+        assert sched.stats()["completed"] == 4
+        spans = collections.defaultdict(list)
+        for name, start, dur in btrace.load(
+                btrace.find_xplane(str(tmp_path)))["host"]:
+            spans[name].append((start, start + dur))
+        parent_of = {"serve.evict": "serve.step",
+                     "serve.evict.read": "serve.evict",
+                     "serve.admit": "serve.step",
+                     "serve.admit.launch": "serve.admit",
+                     "serve.chunk": "serve.step",
+                     "serve.upkeep": "serve.step",
+                     "serve.points": "serve.submit"}
+        for child, parent in parent_of.items():
+            assert spans[child], child
+            for s, e in spans[child]:
+                assert any(ps <= s and e <= pe for ps, pe in spans[parent]), \
+                    (child, parent)
+        assert len(spans["serve.evict.read"]) == 4
+        assert len(spans["serve.points"]) == 2
+        assert len(spans["serve.upkeep"]) == 12
 
     def test_obs_false_profiles_nothing(self):
         sched = self._drive(UOTScheduler(
