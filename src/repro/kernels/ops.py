@@ -536,10 +536,12 @@ def solve_fused(A0: jax.Array, a: jax.Array, b: jax.Array, cfg: UOTConfig,
         # legacy fixed-iteration loop below, so 'auto' keeps tol semantics
         # (per-lane early exit) consistent across the dispatch boundary —
         # results must differ by tier in *traffic*, never in math.
-        P, colsum = solve_fused_batched(
-            A0[None], a[None], b[None], cfg, block_m=block_m,
-            interpret=interpret, storage_dtype=storage_dtype)
-        return P[0], colsum[0]
+        return _profiled(
+            "solve", lambda: _solve_fused_one_streamed(
+                A0, a, b, cfg, block_m=block_m, interpret=interpret,
+                storage_dtype=storage_dtype),
+            M=M, N=N, itemsize=_storage(cfg, storage_dtype).itemsize,
+            impl="streamed", iters=cfg.num_iters)
     return _solve_fused_streamed(A0, a, b, cfg, block_m=block_m,
                                  interpret=interpret,
                                  storage_dtype=storage_dtype)
@@ -636,7 +638,8 @@ def _resolve_auto(impl, M, N, cfg, storage_dtype, *, stepped_sdt=None,
     return resident
 
 
-def _stepped_iter(A, colsum, upd, *, ap, bp, fi, sdt, impl, bm, interpret):
+def _stepped_iter(A, colsum, upd, *, ap, bp, fi, sdt, impl, bm, interpret,
+                  in_place=True):
     """One (optionally masked) batched Algorithm-1 iteration on padded state.
 
     ``upd`` is a (B,) bool lane mask or None. With ``upd=None`` every lane
@@ -651,6 +654,10 @@ def _stepped_iter(A, colsum, upd, *, ap, bp, fi, sdt, impl, bm, interpret):
     O(B*N) colsum keeps an explicit select, pinning the carried-colsum
     value under bf16 storage (recomputing it from a stored bf16 tile would
     drift by a rounding, making results chunk-boundary-dependent).
+
+    The kernels write A' over A's buffer; ``in_place=False`` (masked
+    kernel path only) writes a new one, for a first iteration whose A is
+    the caller's (see ``batched_fused_iteration_frow``).
 
     Returns (A', colsum', frow) where frow (B, M) are this iteration's
     *computed* row factors even for frozen lanes (None on the unmasked
@@ -674,7 +681,8 @@ def _stepped_iter(A, colsum, upd, *, ap, bp, fi, sdt, impl, bm, interpret):
         frow = None
     else:
         newA, newcs, frow = uot_batched.batched_fused_iteration_frow(
-            A, fcol, ap, upd, fi=fi, block_m=bm, interpret=interpret)
+            A, fcol, ap, upd, fi=fi, block_m=bm, interpret=interpret,
+            in_place=in_place)
     if upd is None:
         return newA, newcs, frow
     colsum = jnp.where(upd[:, None], newcs, colsum)
@@ -952,18 +960,39 @@ def _solve_fused_batched_streamed(A0: jax.Array, a: jax.Array, b: jax.Array,
             _, _, _, conv, i = carry
             return jnp.logical_and(i < cfg.num_iters, ~jnp.all(conv))
 
-        def wbody(carry):
+        def wbody(carry, in_place=True):
             A, colsum, prev_frow, conv, i = carry
             upd = ~conv
-            A, colsum, frow = it(A, colsum, upd)
+            A, colsum, frow = it(A, colsum, upd, in_place=in_place)
             drift = lane_factor_drift(frow, prev_frow)
             prev_frow = jnp.where(upd[:, None], frow, prev_frow)
             return A, colsum, prev_frow, conv | (drift <= cfg.tol), i + 1
 
-        Ap, colsum, _, _, _ = jax.lax.while_loop(
-            cond, wbody, (Ap, colsum, jnp.ones_like(ap),
-                          jnp.zeros((B,), bool), jnp.int32(0)))
+        carry = (Ap, colsum, jnp.ones_like(ap), jnp.zeros((B,), bool),
+                 jnp.int32(0))
+        if cfg.num_iters:
+            # The loop's first pass, run before it into a new buffer: the
+            # loop then owns the coupling it writes in place, and the
+            # caller's A0 is never copied.
+            carry = wbody(carry, in_place=False)
+        Ap, colsum, _, _, _ = jax.lax.while_loop(cond, wbody, carry)
     return Ap[:, :M, :N], colsum[:, :N]
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "block_m", "interpret",
+                                             "storage_dtype"))
+def _solve_fused_one_streamed(A0: jax.Array, a: jax.Array, b: jax.Array,
+                              cfg: UOTConfig, *, block_m: int | None = None,
+                              interpret: bool | None = None,
+                              storage_dtype=None):
+    """``_solve_fused_batched_streamed`` on one (M, N) problem, as a batch
+    of one. The batch axis is put on and taken off inside this executable,
+    where both are bitcasts; done eagerly, each is a copy of the coupling.
+    """
+    P, colsum = _solve_fused_batched_streamed(
+        A0[None], a[None], b[None], cfg, block_m=block_m,
+        interpret=interpret, storage_dtype=storage_dtype)
+    return P[0], colsum[0]
 
 
 def solve_fused_resident(A0: jax.Array, a: jax.Array, b: jax.Array,

@@ -72,6 +72,14 @@ def batched_fused_iteration(A: jax.Array, factor_col: jax.Array,
     A: (B, M, N); factor_col: (B, N); a: (B, M). M % block_m == 0 and
     N % 128 == 0 (pre-padded by the ops wrapper). Returns
     (A_next, next_colsum) with next_colsum of shape (B, N) in acc_dtype.
+
+    A_next is written over A's buffer (``input_output_aliases``): every
+    grid step reads tile (b, i) and writes the same tile, and the prefetch
+    of the next tile never touches the one being written back. So a loop
+    that carries A moves the coupling once each way per iteration and
+    copies nothing. A caller that keeps its A sees it unchanged: XLA
+    copies an argument that is still live (or not donated) before the
+    call.
     """
     B, M, N = A.shape
     assert M % block_m == 0, (M, block_m)
@@ -95,6 +103,7 @@ def batched_fused_iteration(A: jax.Array, factor_col: jax.Array,
             jax.ShapeDtypeStruct((B, M, N), A.dtype),
             jax.ShapeDtypeStruct((B, 1, N), acc_dtype),
         ],
+        input_output_aliases={2: 0},
         interpret=interpret,
         compiler_params=COMPILER_PARAMS,
     )(factor_col.reshape(B, 1, N), a.reshape(B, M, 1), A)
@@ -130,11 +139,12 @@ def _batched_fused_iter_frow_kernel(mask_ref, fcol_ref, a_ref, A_ref,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("fi", "block_m", "interpret", "acc_dtype"))
+    jax.jit, static_argnames=("fi", "block_m", "interpret", "acc_dtype",
+                              "in_place"))
 def batched_fused_iteration_frow(A: jax.Array, factor_col: jax.Array,
                                  a: jax.Array, mask: jax.Array, *, fi: float,
                                  block_m: int = 256, interpret: bool = False,
-                                 acc_dtype=jnp.float32):
+                                 acc_dtype=jnp.float32, in_place: bool = True):
     """One masked batched MAP-UOT iteration that also emits the row factors.
 
     The steppable-solver form of ``batched_fused_iteration``: ``mask``
@@ -148,6 +158,14 @@ def batched_fused_iteration_frow(A: jax.Array, factor_col: jax.Array,
     ``ops._stepped_iter`` re-selects the carried value so bf16 storage
     keeps carried-colsum semantics. Returns (A_next, next_colsum, frow);
     frow is the *computed* factor even for frozen lanes (callers mask it).
+
+    A_next is written over A's buffer (``input_output_aliases``), as in
+    ``batched_fused_iteration``; a frozen lane writes its input tile back
+    unchanged. A caller that keeps its A sees it unchanged: XLA copies an
+    argument that is still live (or not donated) before the call.
+    ``in_place=False`` writes a new buffer instead, for the first
+    iteration of a solve whose A is the caller's, which the solve's loop
+    then owns: the caller's A is read once and never copied.
     """
     B, M, N = A.shape
     assert M % block_m == 0, (M, block_m)
@@ -174,6 +192,7 @@ def batched_fused_iteration_frow(A: jax.Array, factor_col: jax.Array,
             jax.ShapeDtypeStruct((B, 1, N), acc_dtype),
             jax.ShapeDtypeStruct((B, M, 1), acc_dtype),
         ],
+        input_output_aliases={3: 0} if in_place else {},
         interpret=interpret,
         compiler_params=COMPILER_PARAMS,
     )(mask.reshape(B, 1, 1).astype(jnp.float32),

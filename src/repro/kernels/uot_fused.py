@@ -91,6 +91,13 @@ def fused_iteration(A: jax.Array, factor_col: jax.Array, a: jax.Array, *,
     wrapper pads with zeros, which the rescaling math is invariant to).
 
     Returns (A_next, next_colsum) with next_colsum fp32 of shape (N,).
+
+    A_next is written over A's buffer (``input_output_aliases``): every
+    grid step reads tile i and writes the same tile, and the prefetch of
+    tile i+1 never touches tile i's write-back. So a loop that carries A
+    moves the coupling once each way per iteration and copies nothing. A
+    caller that keeps its A sees it unchanged: XLA copies an argument
+    that is still live (or not donated) before the call.
     """
     M, N = A.shape
     assert M % block_m == 0, (M, block_m)
@@ -113,6 +120,7 @@ def fused_iteration(A: jax.Array, factor_col: jax.Array, a: jax.Array, *,
             jax.ShapeDtypeStruct((M, N), A.dtype),
             jax.ShapeDtypeStruct((1, N), acc_dtype),
         ],
+        input_output_aliases={2: 0},
         interpret=interpret,
         compiler_params=COMPILER_PARAMS,
     )(factor_col.reshape(1, N), a.reshape(M, 1), A)

@@ -4,6 +4,8 @@ Pallas kernels run with ``impl='kernel', interpret=True`` so the real
 (batch, row_blocks) grid schedule executes on CPU CI; the vectorized XLA
 path (``impl='jnp'``, the non-TPU default) is held to the same parity bars.
 """
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -13,8 +15,9 @@ from repro.core import (UOTConfig, sinkhorn_uot_fused,
                         sinkhorn_uot_fused_batched)
 from repro.kernels import ops, ref
 from repro.kernels.uot_batched import (
-    batched_colsum, batched_fused_iteration, batched_materialize_coupling,
-    batched_uv_iteration)
+    batched_colsum, batched_fused_iteration, batched_fused_iteration_frow,
+    batched_materialize_coupling, batched_uv_iteration)
+from repro.kernels.uot_fused import fused_iteration
 from repro.serve import UOTBatchEngine
 
 
@@ -59,7 +62,6 @@ class TestBatchedKernels:
     def test_matches_single_problem_kernel_per_slice(self):
         """The batched grid must reproduce the single-problem kernel exactly
         (same block schedule per problem -> same accumulation order)."""
-        from repro.kernels.uot_fused import fused_iteration
         B, M, N, bm = 3, 64, 256, 16
         A, fcol, a = rand((B, M, N)), rand((B, N), 1), rand((B, M), 2)
         out, cs = batched_fused_iteration(A, fcol, a, fi=0.9, block_m=bm,
@@ -70,6 +72,33 @@ class TestBatchedKernels:
             np.testing.assert_array_equal(np.asarray(out[i]),
                                           np.asarray(out_i))
             np.testing.assert_array_equal(np.asarray(cs[i]), np.asarray(cs_i))
+
+    @pytest.mark.parametrize("kernel", ["fused", "batched", "frow"])
+    def test_in_place_kernel_leaves_the_callers_array(self, kernel):
+        """The iteration kernels write A' over A's buffer. A caller that
+        keeps its A still sees it unchanged, and the outputs match the
+        oracle (a frozen lane of the masked kernel gives back its input)."""
+        B, M, N, bm = 2, 64, 256, 16
+        A, fcol, a = rand((B, M, N)), rand((B, N), 1), rand((B, M), 2)
+        out_r, cs_r = ref.batched_fused_iteration_ref(A, fcol, a, fi=0.9)
+        if kernel == "fused":
+            A, fcol, a, out_r, cs_r = A[0], fcol[0], a[0], out_r[0], cs_r[0]
+        before = np.array(A)
+        if kernel == "fused":
+            out, cs = fused_iteration(A, fcol, a, fi=0.9, block_m=bm,
+                                      interpret=True)
+        elif kernel == "batched":
+            out, cs = batched_fused_iteration(A, fcol, a, fi=0.9,
+                                              block_m=bm, interpret=True)
+        else:
+            out, cs, _ = batched_fused_iteration_frow(
+                A, fcol, a, jnp.array([1.0, 0.0]), fi=0.9, block_m=bm,
+                interpret=True)
+            out_r = out_r.at[1].set(A[1])
+            cs_r = cs_r.at[1].set(A[1].sum(axis=0))
+        np.testing.assert_array_equal(np.asarray(A), before)
+        np.testing.assert_allclose(out, out_r, rtol=2e-6, atol=1e-8)
+        np.testing.assert_allclose(cs, cs_r, rtol=1e-5)
 
     def test_colsum(self):
         A = rand((3, 96, 256))
@@ -286,6 +315,38 @@ class TestPerLaneEarlyExit:
             np.testing.assert_allclose(P[i], A_core, rtol=3e-5, atol=1e-8)
         assert iter_counts[0] < iter_counts[1], \
             "test needs heterogeneous convergence to mean anything"
+
+    @pytest.mark.parametrize("num_iters", [0, 1, 2, 300])
+    def test_kernel_stops_each_lane_where_jnp_does(self, num_iters):
+        """The kernel path runs the tol loop's first pass before the loop,
+        into a new buffer. Its couplings and column sums match the jnp
+        path's, and each lane's coupling is the fixed-iteration solve at
+        the iteration where that lane stopped, not one before."""
+        cfg = UOTConfig(reg=0.1, reg_m=1.0, num_iters=num_iters, tol=1e-4)
+        K, a, b = self._stack()
+        P, cs = ops.solve_fused_batched(K, a, b, cfg, block_m=16,
+                                        interpret=True, impl="kernel")
+        P_jnp, cs_jnp = ops.solve_fused_batched(K, a, b, cfg, impl="jnp")
+        np.testing.assert_allclose(P, P_jnp, rtol=1e-5, atol=1e-8)
+        np.testing.assert_allclose(cs, cs_jnp, rtol=1e-5)
+
+        def fixed(i, n):
+            return ops.solve_fused_batched(
+                K, a, b, dataclasses.replace(cfg, tol=None, num_iters=n),
+                impl="jnp")[0][i]
+
+        stops = []
+        for i in range(2):
+            stop = int(sinkhorn_uot_fused(K[i], a[i], b[i], cfg)[1]["iters"])
+            np.testing.assert_allclose(P[i], fixed(i, stop), rtol=1e-5,
+                                       atol=1e-8)
+            if stop:
+                gap = np.abs(P[i] - fixed(i, stop)).max()
+                assert np.abs(P[i] - fixed(i, stop - 1)).max() > 10 * gap
+            stops.append(stop)
+        if num_iters > 2:
+            assert stops[0] < stops[1], \
+                "test needs heterogeneous convergence to mean anything"
 
     def test_matches_stepped_lane_pool(self):
         cfg = UOTConfig(reg=0.1, reg_m=1.0, num_iters=300, tol=1e-4)

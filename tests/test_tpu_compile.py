@@ -13,8 +13,10 @@ runs this file loads the TPU compiler, and only once a test has started.
 The persistent compilation cache is off around each compile (an entry
 written without a chip cannot be read back).
 """
+import collections
 import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -164,3 +166,45 @@ def test_cluster_stepped_on_four_chips(topo):
     with _no_persistent_cache():
         compiled = fn.lower(st).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _full_size_copies(hlo: str, dims: str) -> collections.Counter:
+    """Copies of an f32 array of ``dims`` in a compiled HLO module, by
+    where they sit: ``body`` (a ``while`` body), ``entry`` or ``other``."""
+    bodies = set(re.findall(r"body=%?([\w.-]+)", hlo))
+    where, found = None, collections.Counter()
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.-]+) \(.*\{$", line)
+        if head:
+            where = ("entry" if head.group(1) else
+                     "body" if head.group(2) in bodies else "other")
+        elif re.search(rf"= f32\[{dims}\]\S* copy(-start)?\(", line):
+            found[where] += 1
+    return found
+
+
+def test_streamed_solve_keeps_its_coupling_in_place(one_chip, monkeypatch):
+    """The streamed solve that ``solve_fused(impl='auto')`` runs at 20480²
+    copies its coupling nowhere: the iteration kernel writes in place, the
+    loop's first pass writes the buffer the loop then owns, and the batch
+    axis goes on and off inside the executable."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)  # the chip's defaults
+    n = 20480
+    assert not ops.resident_fits(n, n, CFG)
+    compiled = _compile(
+        lambda A, a, b: ops._solve_fused_one_streamed(A, a, b, CFG),
+        *_shapes(one_chip, ((n, n), jnp.float32), ((n,), jnp.float32),
+                 ((n,), jnp.float32)))
+    copies = _full_size_copies(compiled.as_text(), rf"(1,)?{n},{n}")
+    assert copies["body"] == 0, copies
+    assert copies["entry"] == 0, copies
+
+
+def test_scheduler_streamed_chunk_loop_copies_no_pool(one_chip):
+    """The scheduler's streamed chunk carries the lane pool's couplings
+    through its loop without a copy per iteration."""
+    L, M, N = POOL
+    compiled = _compile(lambda s: ops._solve_fused_stepped_streamed(
+        s, 10, CFG, impl="kernel", interpret=False), _pool_shapes(one_chip))
+    copies = _full_size_copies(compiled.as_text(), rf"{L},{M},{N}")
+    assert copies["body"] == 0, copies
