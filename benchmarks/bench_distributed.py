@@ -37,7 +37,7 @@ mesh = jax.make_mesh((%(p)d,), ("rows",))
 solver = rowsharded_fused_solver(mesh, "rows", cfg)
 sA, sa, sb = shard_inputs(mesh, "rows", K, a, b)
 ref, _ = sinkhorn_uot_fused(K, a, b, cfg)
-A, _ = solver(sA, sa, sb)
+A, _, _ = solver(sA, sa, sb)
 ok = bool(jnp.allclose(A, ref, rtol=3e-5, atol=1e-8))
 jax.block_until_ready(solver(sA, sa, sb))
 t0 = time.perf_counter(); jax.block_until_ready(solver(sA, sa, sb))

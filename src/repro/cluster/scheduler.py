@@ -1389,7 +1389,7 @@ class ClusterScheduler:
                 P, _ = distributed.gang_solve(
                     self.mesh, self.axis, K, req.a, req.b, cfg,
                     storage_dtype=self.storage_dtype,
-                    overlapped=self.gang_overlapped)
+                    overlapped=self.gang_overlapped, obs=self.obs)
             else:
                 P, _ = ops.solve_fused(
                     jnp.asarray(K), jnp.asarray(req.a), jnp.asarray(req.b),

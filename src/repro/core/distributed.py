@@ -9,6 +9,14 @@ coupling matrix across MPI ranks; the only communication per iteration is an
   row-shard of A      -> shard_map block of A sharded on that axis
   MPI_Allreduce       -> jax.lax.psum of the local column-sum partials
 
+The row-sharded gang (``rowsharded_fused_solver``) is not a loop of its
+own: each device runs the streamed kernel tier's solve loop,
+``kernels.ops.streamed_solve``, on its row block, with the psum where one
+device sums alone and the ``cfg.tol`` drift taken over the mesh, so it
+stops on the same iteration with the same iterate as the one-device
+solve. ``gang_solve_sharded`` runs it on inputs already on the mesh and
+returns the iteration count.
+
 Beyond the paper we add:
   * a 2-D sharded solver (rows on one axis, columns on another) for matrices
     too large for 1-D sharding — row sums psum over the column axis and
@@ -27,7 +35,8 @@ Beyond the paper we add:
 
 All variants produce iterates identical to ``sinkhorn_uot_fused`` (up to
 float reduction order; bf16 storage to the documented bf16 bars) —
-asserted in tests on 8 forced host devices.
+asserted in tests on forced host devices. The 2-D and overlapped variants
+run the fixed ``cfg.num_iters`` in plain XLA.
 """
 from __future__ import annotations
 
@@ -39,6 +48,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.problem import UOTConfig, rescale_factors
+from repro.kernels import ops
 
 
 def _storage(cfg: UOTConfig, storage_dtype) -> jnp.dtype:
@@ -50,48 +60,41 @@ def _storage(cfg: UOTConfig, storage_dtype) -> jnp.dtype:
 # 1-D row-sharded MAP-UOT (the paper's cluster design)
 # ---------------------------------------------------------------------------
 
-def rowsharded_fused_solver(mesh: Mesh, axis: str, cfg: UOTConfig, *,
-                            storage_dtype=None):
-    """Build a jit-able solver fn over a row-sharded coupling matrix.
+GANG_SPAN = "gang.solve"
 
-    Returns solve(A, a, b) -> (A, colsum) where A is sharded P(axis, None)
-    and a is sharded P(axis); b is replicated. One psum (== MPI_Allreduce)
-    per iteration.
+
+def rowsharded_fused_solver(mesh: Mesh, axis: str, cfg: UOTConfig, *,
+                            storage_dtype=None, impl: str | None = None):
+    """Build the jitted row-sharded gang solve over ``mesh``'s ``axis``.
+
+    Returns ``solve(K, a, b) -> (A, colsum, iters)``. K is sharded
+    ``P(axis, None)``, a ``P(axis)`` and b replicated; A comes back
+    sharded like K, colsum and the iteration count replicated.
+
+    Each device runs the streamed tier's solve loop
+    (``ops.streamed_solve``) on its row block, with the MAP-UOT kernels
+    where ``ops.solve_fused`` would run them: one psum (==
+    MPI_Allreduce) of the fp32 column-sum partials per iteration, and
+    with ``cfg.tol`` the same stopping rule and iterate as the one-device
+    solve, the drift taken over the whole mesh. The loop writes each
+    device's coupling in place; the caller's K is read and not copied.
 
     ``storage_dtype`` (default ``cfg.dtype``) is the dtype each device
-    carries its row block in between iterations; the rescale math and
-    every reduction (local sums AND the psum) run fp32, so a bf16 gang
-    halves per-device residency without touching collective precision.
-    The returned coupling is in the storage dtype, the colsum fp32.
+    keeps its row block in between iterations; the rescale math and every
+    reduction (local sums and the psum) run fp32. ``impl`` as in
+    ``ops.solve_fused_batched`` ('kernel' on TPU by default, 'jnp'
+    elsewhere).
     """
-    fi = cfg.fi
-    sdt = _storage(cfg, storage_dtype)
-
-    def local_iter(A_blk, colsum, a_blk, b):
-        # Column rescale with globally-reduced column sums (already psum'ed)
-        blk = A_blk.astype(jnp.float32) * rescale_factors(b, colsum, fi)[None, :]
-        rowsum = blk.sum(axis=1)
-        blk = blk * rescale_factors(a_blk, rowsum, fi)[:, None]
-        # Partial column sums of the local row block -> allreduce (fp32)
-        partial = blk.sum(axis=0)
-        return blk.astype(sdt), jax.lax.psum(partial, axis)
-
-    def solve_shard(A_blk, a_blk, b):
-        A_blk = A_blk.astype(sdt)
-        colsum = jax.lax.psum(A_blk.astype(jnp.float32).sum(axis=0), axis)
-
-        def body(_, carry):
-            A_blk, colsum = carry
-            return local_iter(A_blk, colsum, a_blk, b)
-
-        A_blk, colsum = jax.lax.fori_loop(
-            0, cfg.num_iters, body, (A_blk, colsum))
-        return A_blk, colsum
+    def solve_shard(K_blk, a_blk, b):
+        A, colsum, iters = ops.streamed_solve(
+            K_blk[None], a_blk[None], b[None], cfg,
+            storage_dtype=storage_dtype, impl=impl, axis=axis)
+        return A[0], colsum[0], iters
 
     sharded = jax.shard_map(
         solve_shard, mesh=mesh,
         in_specs=(P(axis, None), P(axis), P()),
-        out_specs=(P(axis, None), P()),
+        out_specs=(P(axis, None), P(), P()),
         check_vma=False)
     return jax.jit(sharded)
 
@@ -224,7 +227,7 @@ def shard_inputs(mesh: Mesh, axis: str, A, a, b):
 
 
 # ---------------------------------------------------------------------------
-# Serving-tier gang entry: one adapter from a raw request to the row gang
+# Gang entries: device-resident inputs, and the serving tier's host adapter
 # ---------------------------------------------------------------------------
 
 # Built solver fns per (mesh, axis, cfg, storage dtype, num_chunks-or-None):
@@ -232,9 +235,55 @@ def shard_inputs(mesh: Mesh, axis: str, A, a, b):
 _GANG_SOLVERS: dict = {}
 
 
+def _gang_solver(mesh: Mesh, axis: str, cfg: UOTConfig, storage_dtype,
+                 num_chunks: int | None = None):
+    sdt = _storage(cfg, storage_dtype)
+    key = (mesh, axis, cfg, sdt.name, num_chunks)
+    solver = _GANG_SOLVERS.get(key)
+    if solver is None:
+        solver = _GANG_SOLVERS[key] = (
+            rowsharded_fused_solver(mesh, axis, cfg,
+                                    storage_dtype=storage_dtype)
+            if num_chunks is None
+            else rowsharded_overlapped_solver(mesh, axis, cfg,
+                                              num_chunks=num_chunks,
+                                              storage_dtype=storage_dtype))
+    return solver
+
+
+def gang_solve_sharded(mesh: Mesh, axis: str, K, a, b, cfg: UOTConfig, *,
+                       storage_dtype=None, obs=None):
+    """Solve one problem whose inputs are already on the mesh.
+
+    K (M, N) sharded ``P(axis, None)``, a ``P(axis)``, b replicated, with
+    M a multiple of the axis size (``shard_inputs`` places host arrays
+    so). Runs ``rowsharded_fused_solver``, built once per (mesh, axis,
+    cfg, storage dtype), and waits for it. Returns ``(A, colsum, iters)``
+    with A left sharded like K and ``iters`` a Python int.
+
+    ``obs`` (default: the process-global ``repro.obs`` bundle) gets a
+    ``gang.solve`` phase over the launch and the wait, which is a
+    ``TraceAnnotation`` on the device trace's clock, and two counters:
+    ``gang.iters``, the iterations run, and ``gang.allreduce_bytes``, the
+    per-device all-reduce bytes ``obs.traffic.gang_collective_bytes``
+    charges for them.
+    """
+    from repro.obs import gang_collective_bytes, get_global
+
+    obs = get_global() if obs is None else obs
+    solver = _gang_solver(mesh, axis, cfg, storage_dtype)
+    with obs.phases.phase(GANG_SPAN):
+        A, colsum, iters = solver(K, a, b)
+        iters = int(iters)
+    obs.registry.counter("gang.iters").inc(iters)
+    obs.registry.counter("gang.allreduce_bytes").inc(
+        gang_collective_bytes(K.shape[1], iters))
+    return A, colsum, iters
+
+
 def gang_solve(mesh: Mesh, axis: str, K, a, b, cfg: UOTConfig, *,
                storage_dtype=None, overlapped: bool = False,
-               num_chunks: int = 4):
+               num_chunks: int = 4, obs=None):
     """Solve one over-sized request on the row-sharded device gang.
 
     The serving-tier entry adapter that unifies the lane-pool and
@@ -246,14 +295,14 @@ def gang_solve(mesh: Mesh, axis: str, K, a, b, cfg: UOTConfig, *,
         zero marginal mass -> unit factors -> stay zero: exact no-ops,
         the same invariant the lane pools rest on);
       * inputs are placed with ``shard_inputs`` (one host->device scatter
-        of O(M*N/D) bytes per device), the compiled gang solver is built
-        once per (mesh, axis, cfg, storage dtype) and cached;
+        of O(M*N/D) bytes per device) and solved by
+        ``gang_solve_sharded`` (``obs`` as there);
       * the result is trimmed back to (M, N) host numpy.
 
-    Runs the fixed ``cfg.num_iters`` budget (the gang's fori_loop has no
-    tol early-exit — one over-sized solve saturates the mesh, so there is
-    no lane-mate to stop dragging). Returns ``(P, colsum)`` numpy arrays.
-    ``overlapped=True`` uses the ring-reduce compute/comm-overlap variant.
+    Honours ``cfg.tol`` as the one-device solve does. Returns
+    ``(P, colsum)`` numpy arrays. ``overlapped=True`` uses the
+    ring-reduce compute/comm-overlap variant, which runs the fixed
+    ``cfg.num_iters``.
     """
     K = np.asarray(K)
     M, N = K.shape
@@ -268,18 +317,14 @@ def gang_solve(mesh: Mesh, axis: str, K, a, b, cfg: UOTConfig, *,
         K = np.pad(K, ((0, pm), (0, 0)))
         a = np.pad(np.asarray(a), (0, pm))
     sdt = _storage(cfg, storage_dtype)
-    key = (mesh, axis, cfg, sdt.name, num_chunks if overlapped else None)
-    solver = _GANG_SOLVERS.get(key)
-    if solver is None:
-        solver = _GANG_SOLVERS[key] = (
-            rowsharded_overlapped_solver(mesh, axis, cfg,
-                                         num_chunks=num_chunks,
-                                         storage_dtype=storage_dtype)
-            if overlapped
-            else rowsharded_fused_solver(mesh, axis, cfg,
-                                         storage_dtype=storage_dtype))
     sA, sa, sb = shard_inputs(mesh, axis, jnp.asarray(K, sdt),
                               jnp.asarray(a, jnp.float32),
                               jnp.asarray(b, jnp.float32))
-    A, colsum = solver(sA, sa, sb)
+    if overlapped:
+        A, colsum = _gang_solver(mesh, axis, cfg, storage_dtype,
+                                 num_chunks)(sA, sa, sb)
+    else:
+        A, colsum, _ = gang_solve_sharded(mesh, axis, sA, sa, sb, cfg,
+                                          storage_dtype=storage_dtype,
+                                          obs=obs)
     return np.asarray(A)[:M], np.asarray(colsum)

@@ -104,8 +104,11 @@ traffic formulas describe the TPU kernels. The cluster tier —
 ``repro.cluster``'s sharded lane pools — is the scheduler row times D
 devices: per-device traffic is unchanged, the only cross-device bytes are
 admission payloads to the owning shard. Problems too large for any lane
-pool bypass this table entirely and run on the row-sharded gang solvers,
-``core.distributed.gang_solve``: O(N) allreduce bytes per iteration.)
+pool, or for one chip, run on the row-sharded gang,
+``core.distributed.gang_solve`` / ``gang_solve_sharded``: each device runs
+this module's streamed tier loop, ``streamed_solve``, on its row block,
+so its traffic is the streamed column over M/D rows, plus O(N) allreduce
+bytes per iteration.)
 
 Traffic accounting: the table above is executable. ``repro.obs.traffic``
 implements each cell as a formula function (``solve_bytes`` /
@@ -214,20 +217,25 @@ def streamed_vmem_bytes(block_m: int, N: int, itemsize: int = 4,
 
     The pipeline double-buffers the in and out ``(block_m, N)`` tiles in
     the storage dtype, and the kernel body holds one ``acc_itemsize``
-    working copy of the tile: ``block_m * N * (4*s + acc)``. On top come
-    the O(block_m + N) factor, marginal and column-sum blocks. The
-    implicit-geometry kernels (``uot_geometry``) load no input tile, but
-    Mosaic keeps about ten ``acc_itemsize`` temporaries of the cost-tile
-    arithmetic and the whole double-buffered ``(N, d)`` column cloud (one
-    lane tile wide for d <= 128): ``block_m * N * (2*s + 10*acc)`` plus
-    the cloud, about twice the dense tier's bytes per row. Checked
-    against the v5e compiler in tests/test_tpu_compile.py.
+    working copy of the tile, plus, for a storage dtype narrower than
+    ``acc``, the tile cast back to storage before its store:
+    ``block_m * N * (4*s + acc)``, ``+ s`` when ``s < acc`` (measured on
+    the v5e compiler: 50.0 MiB for the fp32 frow kernel at
+    (128, 20480) and at (32, 81920), 35.2 MiB for bf16 at (128, 20480)).
+    On top come the O(block_m + N) factor, marginal and column-sum
+    blocks. The implicit-geometry kernels (``uot_geometry``) load no input
+    tile, but Mosaic keeps about ten ``acc_itemsize`` temporaries of the
+    cost-tile arithmetic and the whole double-buffered ``(N, d)`` column
+    cloud (one lane tile wide for d <= 128): ``block_m * N * (2*s +
+    10*acc)`` plus the cloud, about twice the dense tier's bytes per row.
+    Checked against the v5e compiler in tests/test_tpu_compile.py.
     """
     if implicit:
         per_elt = 2 * itemsize + 10 * acc_itemsize
         cloud = _vector_bytes(N, 0, 2)
     else:
-        per_elt = 4 * itemsize + acc_itemsize
+        per_elt = 4 * itemsize + acc_itemsize + (
+            itemsize if itemsize < acc_itemsize else 0)
         cloud = 0
     return block_m * N * per_elt + _vector_bytes(block_m, N, 4) + cloud
 
@@ -639,7 +647,7 @@ def _resolve_auto(impl, M, N, cfg, storage_dtype, *, stepped_sdt=None,
 
 
 def _stepped_iter(A, colsum, upd, *, ap, bp, fi, sdt, impl, bm, interpret,
-                  in_place=True):
+                  in_place=True, axis=None):
     """One (optionally masked) batched Algorithm-1 iteration on padded state.
 
     ``upd`` is a (B,) bool lane mask or None. With ``upd=None`` every lane
@@ -658,6 +666,9 @@ def _stepped_iter(A, colsum, upd, *, ap, bp, fi, sdt, impl, bm, interpret,
     The kernels write A' over A's buffer; ``in_place=False`` (masked
     kernel path only) writes a new one, for a first iteration whose A is
     the caller's (see ``batched_fused_iteration_frow``).
+
+    With ``axis`` (a mesh axis name) A is one device's row block and the
+    new column sums are summed over the axis before anything reads them.
 
     Returns (A', colsum', frow) where frow (B, M) are this iteration's
     *computed* row factors even for frozen lanes (None on the unmasked
@@ -683,6 +694,8 @@ def _stepped_iter(A, colsum, upd, *, ap, bp, fi, sdt, impl, bm, interpret,
         newA, newcs, frow = uot_batched.batched_fused_iteration_frow(
             A, fcol, ap, upd, fi=fi, block_m=bm, interpret=interpret,
             in_place=in_place)
+    if axis is not None:
+        newcs = jax.lax.psum(newcs, axis)
     if upd is None:
         return newA, newcs, frow
     colsum = jnp.where(upd[:, None], newcs, colsum)
@@ -931,6 +944,30 @@ def _solve_fused_batched_streamed(A0: jax.Array, a: jax.Array, b: jax.Array,
                                   interpret: bool | None = None,
                                   storage_dtype=None,
                                   impl: str | None = None):
+    P, colsum, _ = streamed_solve(A0, a, b, cfg, block_m=block_m,
+                                  interpret=interpret,
+                                  storage_dtype=storage_dtype, impl=impl)
+    return P, colsum
+
+
+def streamed_solve(A0: jax.Array, a: jax.Array, b: jax.Array,
+                   cfg: UOTConfig, *, block_m: int | None = None,
+                   interpret: bool | None = None, storage_dtype=None,
+                   impl: str | None = None, axis: str | None = None):
+    """The streamed tier's solve loop over a (B, M, N) stack, unjitted.
+
+    Returns ``(P, colsum, iters)``; ``iters`` is the number of passes the
+    loop ran (with ``cfg.tol``, the iteration count of a batch of one).
+
+    With ``axis`` (a mesh axis name, inside ``shard_map``) the stack is
+    one device's row block of a larger problem, the row-sharded gang of
+    ``core.distributed``: the column-sum partials are summed over the
+    axis after the first pass and after every iteration (the paper's
+    ``MPI_Allreduce``, 4*N bytes a lane), and each lane's row-factor
+    drift is the largest over the axis, so every device leaves the loop
+    on the same iteration with the same iterate as one device would.
+    Without ``axis`` it is exactly the one-device loop.
+    """
     interpret = _interpret_default(interpret)
     impl = _impl_default(impl, interpret)
     B, M, N = A0.shape
@@ -946,15 +983,18 @@ def _solve_fused_batched_streamed(A0: jax.Array, a: jax.Array, b: jax.Array,
     else:
         colsum = uot_batched.batched_colsum(
             Ap, block_m=bm, interpret=interpret)
+    if axis is not None:
+        colsum = jax.lax.psum(colsum, axis)
 
     it = functools.partial(_stepped_iter, ap=ap, bp=bp, fi=fi, sdt=sdt,
-                           impl=impl, bm=bm, interpret=interpret)
+                           impl=impl, bm=bm, interpret=interpret, axis=axis)
     if cfg.tol is None:
         def body(_, carry):
             A, colsum = carry
             A, colsum, _ = it(A, colsum, None)
             return A, colsum
         Ap, colsum = jax.lax.fori_loop(0, cfg.num_iters, body, (Ap, colsum))
+        iters = jnp.int32(cfg.num_iters)
     else:
         def cond(carry):
             _, _, _, conv, i = carry
@@ -965,6 +1005,8 @@ def _solve_fused_batched_streamed(A0: jax.Array, a: jax.Array, b: jax.Array,
             upd = ~conv
             A, colsum, frow = it(A, colsum, upd, in_place=in_place)
             drift = lane_factor_drift(frow, prev_frow)
+            if axis is not None:
+                drift = jax.lax.pmax(drift, axis)
             prev_frow = jnp.where(upd[:, None], frow, prev_frow)
             return A, colsum, prev_frow, conv | (drift <= cfg.tol), i + 1
 
@@ -975,8 +1017,8 @@ def _solve_fused_batched_streamed(A0: jax.Array, a: jax.Array, b: jax.Array,
             # loop then owns the coupling it writes in place, and the
             # caller's A0 is never copied.
             carry = wbody(carry, in_place=False)
-        Ap, colsum, _, _, _ = jax.lax.while_loop(cond, wbody, carry)
-    return Ap[:, :M, :N], colsum[:, :N]
+        Ap, colsum, _, _, iters = jax.lax.while_loop(cond, wbody, carry)
+    return Ap[:, :M, :N], colsum[:, :N], iters
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "block_m", "interpret",

@@ -44,7 +44,8 @@ def main():
     mesh = jax.make_mesh((8,), ("rows",))
     solver = rowsharded_fused_solver(mesh, "rows", cfg)
     sA, sa, sb = shard_inputs(mesh, "rows", K, a, b)
-    A1, colsum = solver(sA, sa, sb)
+    A1, colsum, iters = solver(sA, sa, sb)
+    assert int(iters) == cfg.num_iters, int(iters)
     np.testing.assert_allclose(np.asarray(A1), ref, rtol=3e-5, atol=1e-8)
     np.testing.assert_allclose(np.asarray(colsum), ref.sum(0), rtol=3e-4)
     print("rowsharded: OK")
@@ -99,7 +100,7 @@ def main():
             sA16 = jax.device_put(K, NamedSharding(m, P("r", "c")))
             sa16 = jax.device_put(a, NamedSharding(m, P("r")))
             sb16 = jax.device_put(b, NamedSharding(m, P("c")))
-        A16, cs16 = solver16(sA16, sa16, sb16)
+        A16, cs16 = solver16(sA16, sa16, sb16)[:2]
         assert A16.dtype == bf16, (name, A16.dtype)
         assert cs16.dtype == jnp.float32, (name, cs16.dtype)
         err = float(np.abs(np.asarray(A16, np.float32) - ref).max())

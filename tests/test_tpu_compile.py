@@ -208,3 +208,67 @@ def test_scheduler_streamed_chunk_loop_copies_no_pool(one_chip):
         s, 10, CFG, impl="kernel", interpret=False), _pool_shapes(one_chip))
     copies = _full_size_copies(compiled.as_text(), rf"{L},{M},{N}")
     assert copies["body"] == 0, copies
+
+
+def _all_reduces(hlo: str) -> collections.Counter:
+    """All-reduces in a compiled HLO module by (where, result shape), with
+    ``where`` as in ``_full_size_copies``."""
+    bodies = set(re.findall(r"body=%?([\w.-]+)", hlo))
+    where, found = None, collections.Counter()
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.-]+) \(.*\{$", line)
+        if head:
+            where = ("entry" if head.group(1) else
+                     "body" if head.group(2) in bodies else "other")
+        else:
+            op = re.search(r"= (\w+\[[\d,]*\])\S* all-reduce(-start)?\(", line)
+            if op:
+                found[where, op.group(1)] += 1
+    return found
+
+
+def test_gang_on_four_chips_reduces_once_an_iteration_and_copies_nothing(
+        topo, one_chip, monkeypatch):
+    """The ``gang80k`` cell's solve, 81920² over a 2x2 host: each chip runs
+    the streamed loop on its (20480, 81920) row block, with one all-reduce
+    of the fp32 column sums and one of the drift in the ``while`` body, and
+    no copy of a row block anywhere. The one-device loop at that block
+    holds no collective."""
+    from repro.core.distributed import rowsharded_fused_solver
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)  # the chip's defaults
+    n, rows = 81920, 81920 // 4
+    mesh = jax.sharding.Mesh(np.array(topo.devices), ("devices",))
+    specs = [((n, n), P("devices", None)), ((n,), P("devices")), ((n,), P())]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32,
+                                 sharding=NamedSharding(mesh, p))
+            for s, p in specs]
+    with _no_persistent_cache():
+        compiled = rowsharded_fused_solver(mesh, "devices", CFG).lower(
+            *args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    reduces = _all_reduces(hlo)
+    assert reduces["body", f"f32[1,{n}]"] == 1, reduces
+    assert reduces["body", "f32[1]"] == 1, reduces
+    assert sum(k for (w, _), k in reduces.items() if w == "body") == 2
+    copies = _full_size_copies(hlo, rf"(1,)?{rows},{n}")
+    assert copies["body"] == 0 and copies["entry"] == 0, copies
+
+    one = _compile(
+        lambda A, a, b: ops._solve_fused_one_streamed(A, a, b, CFG),
+        *_shapes(one_chip, ((rows, n), jnp.float32), ((rows,), jnp.float32),
+                 ((n,), jnp.float32)))
+    assert not _all_reduces(one.as_text())
+
+
+def test_streamed_solve_compiles_in_bfloat16(one_chip, monkeypatch):
+    """bf16 storage at 20480²: the block ``pick_block_m`` chooses counts
+    the tile cast back to bf16 before its store, so the frow kernel fits
+    its scoped VMEM."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    n, cfg = 20480, UOTConfig(reg=0.05, reg_m=1.0, num_iters=300, tol=1e-4,
+                              dtype=jnp.bfloat16)
+    assert ops.pick_block_m(n, n, 2) == 128
+    _compile(lambda A, a, b: ops._solve_fused_one_streamed(A, a, b, cfg),
+             *_shapes(one_chip, ((n, n), jnp.bfloat16), ((n,), jnp.float32),
+                      ((n,), jnp.float32)))
