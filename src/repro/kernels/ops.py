@@ -27,7 +27,9 @@ Steppable solving (continuous batching)
 ``LaneState`` + ``solve_fused_stepped`` expose the batched solve as
 explicit carried state advanced a chunk of iterations per call, with
 per-lane ``lane_admit`` / ``lane_evict`` / ``lane_done`` lifecycle — the
-substrate for ``repro.serve.scheduler``'s continuous batching. With
+substrate for ``repro.serve.scheduler``'s continuous batching, which
+admits through ``lane_admit_packed`` (two host buffers, one donated
+launch a pool update). With
 ``cfg.tol`` set, both the stepped and the one-shot batched solves freeze
 each lane at the iterate where its row-factor stationarity reaches tol
 (identical to the single-problem solvers' early exit, per lane).
@@ -174,6 +176,7 @@ import numpy as np
 from repro.core.convergence import lane_factor_drift
 from repro.core.problem import UOTConfig, rescale_factors
 from repro.geometry import Geometry, PointCloudGeometry
+from repro.geometry.pointcloud import _kernel_mirror, valid_mask
 from repro.kernels import (uot_batched, uot_fused, uot_geometry,
                            uot_halfpass, uot_resident, uot_uv_fused)
 from repro.kernels.vmem import VMEM_LIMIT_BYTES
@@ -1321,6 +1324,11 @@ def lane_admit(state: LaneState, lane, K: jax.Array, a: jax.Array,
     bucket shape (appended zeros are exact identities of every reduction;
     property-tested in tests/test_cluster.py).
     """
+    return _lane_admit(state, lane, K, a, b, m_valid, n_valid)
+
+
+def _lane_admit(state: LaneState, lane, K, a, b, m_valid,
+                n_valid) -> LaneState:
     Mp, Np = state.P.shape[1:]
     Kp, ap, bp, mv, nv = _pad_admit_payload(Mp, Np, K, a, b, m_valid,
                                             n_valid, state.P.dtype)
@@ -1336,6 +1344,77 @@ def lane_admit(state: LaneState, lane, K: jax.Array, a: jax.Array,
         m_valid=state.m_valid.at[lane].set(mv),
         n_valid=state.n_valid.at[lane].set(nv),
         healthy=state.healthy.at[lane].set(True))
+
+
+# ---- packed, donated admission (the serving scheduler's pool update) ------
+#
+# A pool update ships its host payload as ONE float32 and ONE int32 buffer
+# and lands it with one launch that writes the donated pool in place —
+# three device calls, against one transfer per field plus an eager op per
+# step of the mask and the mirror. The layout below is the contract between
+# the host staging (``admit_buffers``) and the launch (``lane_admit_packed``).
+
+def _admit_layout(L: int, Mb: int, Nb: int, d: int | None):
+    """(float32 field shapes, int32 field shapes) of a packed payload of
+    ``L`` problems padded to the bucket (Mb, Nb): dense ``(K, a, b)`` /
+    ``(lanes,)`` when ``d`` is None, else point clouds ``(x, xn, y, yn, a,
+    b)`` / ``(lanes, m_valid, n_valid)``, with the column cloud at the
+    mirror's lane-aligned width so the mirror never pads."""
+    if d is None:
+        return ((L, Mb, Nb), (L, Mb), (L, Nb)), ((L,),)
+    Nc = Nb + (-Nb) % _LANE
+    return (((L, Mb, d), (L, Mb), (L, Nc, d), (L, Nc), (L, Mb), (L, Nb)),
+            ((L,), (L,), (L,)))
+
+
+def _unpack(buf, shapes):
+    """Split a flat buffer into consecutive fields of ``shapes`` — numpy
+    views on the host, slices inside the launch."""
+    out, off = [], 0
+    for s in shapes:
+        n = int(np.prod(s))
+        out.append(buf[off:off + n].reshape(s))
+        off += n
+    return out
+
+
+def admit_buffers(L: int, Mb: int, Nb: int, d: int | None = None):
+    """Zeroed host staging for ``lane_admit_packed``:
+    ``(f32, i32, f32_fields, i32_fields)``. The fields are numpy views
+    into the two flat buffers, so filling them fills what ships."""
+    fs, ints = _admit_layout(L, Mb, Nb, d)
+    f32 = np.zeros(sum(int(np.prod(s)) for s in fs), np.float32)
+    i32 = np.zeros(sum(int(np.prod(s)) for s in ints), np.int32)
+    return f32, i32, _unpack(f32, fs), _unpack(i32, ints)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,),
+                   static_argnames=("bucket", "d", "reg", "scale"))
+def lane_admit_packed(state: LaneState, f32: jax.Array, i32: jax.Array, *,
+                      bucket: tuple[int, int], d: int | None = None,
+                      reg: float = 1.0, scale: float = 1.0) -> LaneState:
+    """One pool update from an ``admit_buffers(state.num_lanes, *bucket,
+    d)`` payload, in one launch that donates (and so writes in place) the
+    pool ``state``.
+
+    Dense (``d`` None): ``lane_admit(state, lanes, K, a, b)``. Point
+    clouds: the Gibbs mirror of ``PointCloudGeometry.kernel(reg)`` —
+    the same jitted mirror, so the same arithmetic and barrier — masked
+    to exact zeros beyond each problem's valid counts, then the same
+    lane scatter. Either way the pool ends bit-identical to the eager
+    path's, and one pool compiles one signature per payload kind.
+    """
+    Mb, Nb = bucket
+    fs, ints = _admit_layout(state.num_lanes, Mb, Nb, d)
+    if d is None:
+        K, a, b = _unpack(f32, fs)
+        (lanes,) = _unpack(i32, ints)
+        return _lane_admit(state, lanes, K, a, b, None, None)
+    x, xn, y, yn, a, b = _unpack(f32, fs)
+    lanes, mv, nv = _unpack(i32, ints)
+    K = _kernel_mirror(x, xn, y, yn, reg=reg, scale=scale)[..., :Nb]
+    K = jnp.where(valid_mask(Mb, Nb, mv, nv), K, 0.0)
+    return _lane_admit(state, lanes, K, a, b, None, None)
 
 
 @jax.jit

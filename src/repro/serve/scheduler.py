@@ -505,6 +505,10 @@ class UOTScheduler:
         self._g_occupancy = reg.gauge("serve.occupancy")
         self._c_dispatch = {k: reg.counter("serve.dispatch." + k)
                             for k in ("resident", "streamed")}
+        # admission's pool updates, and the host-to-device transfers plus
+        # launches they cost
+        self._c_admit_updates = reg.counter("serve.admit.updates")
+        self._c_admit_calls = reg.counter("serve.admit.device_calls")
         # overload-model observability: degrade-ladder activity per
         # level, feasibility refusals, and the iteration predictor's
         # relative absolute error (|predicted - actual| / actual) so the
@@ -1322,10 +1326,7 @@ class UOTScheduler:
         pool = self._pools[bucket]
         Mb, Nb = bucket
         L = pool.num_lanes
-        Kp = np.zeros((L, Mb, Nb), np.float32)
-        ap = np.zeros((L, Mb), np.float32)
-        bp = np.zeros((L, Nb), np.float32)
-        lanes = np.empty(L, np.int32)
+        f32, i32, (Kp, ap, bp), (lanes,) = ops.admit_buffers(L, Mb, Nb)
         for j in range(L):
             lane, req = placed[min(j, len(placed) - 1)]
             M, N = req.shape
@@ -1336,28 +1337,19 @@ class UOTScheduler:
         self.obs.traffic.charge_admission(
             route="lane", M=Mb, N=Nb, s=4, source="dense",
             count=len(placed))
-        with self.obs.phases.phase("serve.admit.launch"):
-            pool.state = ops.lane_admit(
-                pool.state, jnp.asarray(lanes), jnp.asarray(Kp),
-                jnp.asarray(ap), jnp.asarray(bp))
+        self._launch_admit(pool, f32, i32, bucket=bucket)
 
     def _admit_points(self, bucket, placed, d: int, scale: float) -> None:
-        """Admit a round's point-cloud requests: transfer coordinates,
-        materialize the masked Gibbs stack on-device (the geometry
-        mirror's arithmetic, so lanes are bit-identical to dense
-        submission of ``geometry.kernel(cfg.reg)``), one pool update."""
+        """Admit a round's point-cloud requests: ship coordinates and
+        norms, and materialize the masked Gibbs stack on-device inside
+        the admission launch (the geometry mirror's arithmetic, so lanes
+        are bit-identical to dense submission of
+        ``geometry.kernel(cfg.reg)``), one pool update."""
         pool = self._pools[bucket]
         Mb, Nb = bucket
         L = pool.num_lanes
-        xs = np.zeros((L, Mb, d), np.float32)
-        xns = np.zeros((L, Mb), np.float32)
-        ys = np.zeros((L, Nb, d), np.float32)
-        yns = np.zeros((L, Nb), np.float32)
-        mv = np.zeros(L, np.int32)
-        nv = np.zeros(L, np.int32)
-        ap = np.zeros((L, Mb), np.float32)
-        bp = np.zeros((L, Nb), np.float32)
-        lanes = np.empty(L, np.int32)
+        f32, i32, (xs, xns, ys, yns, ap, bp), (lanes, mv, nv) = \
+            ops.admit_buffers(L, Mb, Nb, d)
         for j in range(L):
             lane, req = placed[min(j, len(placed) - 1)]
             M, N = req.shape
@@ -1370,14 +1362,18 @@ class UOTScheduler:
         self.obs.traffic.charge_admission(
             route="lane", M=Mb, N=Nb, s=4, source="implicit", d=d,
             count=len(placed))
+        self._launch_admit(pool, f32, i32, bucket=bucket, d=d,
+                           reg=float(self.cfg.reg), scale=scale)
+
+    def _launch_admit(self, pool: _LanePool, f32: np.ndarray,
+                      i32: np.ndarray, **static) -> None:
+        """One pool update: the two packed host buffers cross to the
+        device and one launch writes them into the donated pool."""
         with self.obs.phases.phase("serve.admit.launch"):
-            g = PointCloudGeometry(
-                x=jnp.asarray(xs), y=jnp.asarray(ys), xn=jnp.asarray(xns),
-                yn=jnp.asarray(yns), m_valid=jnp.asarray(mv),
-                n_valid=jnp.asarray(nv), scale=scale)
-            pool.state = ops.lane_admit(
-                pool.state, jnp.asarray(lanes), g.kernel(self.cfg.reg),
-                jnp.asarray(ap), jnp.asarray(bp))
+            pool.state = ops.lane_admit_packed(pool.state, f32, i32,
+                                               **static)
+        self._c_admit_updates.inc()
+        self._c_admit_calls.inc(2 + 1)   # two transfers, one launch
 
     def _snapshot_occupancy(self) -> None:
         occ = {str(b): p.occupancy for b, p in self._pools.items()}

@@ -210,6 +210,26 @@ def test_scheduler_streamed_chunk_loop_copies_no_pool(one_chip):
     assert copies["body"] == 0, copies
 
 
+@pytest.mark.parametrize("d", [None, 3, 32], ids=["dense", "d3", "d32"])
+def test_scheduler_admission_writes_the_pool_in_place(one_chip, d):
+    """The scheduler's pool update, point-cloud Gibbs tiles built inside
+    it, aliases every field of the donated pool to its output and copies
+    no pool."""
+    L, M, N = POOL
+    f32, i32, _, _ = ops.admit_buffers(L, M, N, d)
+    payload = _shapes(one_chip, (f32.shape, f32.dtype),
+                      (i32.shape, i32.dtype))
+    with _no_persistent_cache():
+        compiled = ops.lane_admit_packed.lower(
+            _pool_shapes(one_chip), *payload, bucket=(M, N), d=d,
+            reg=CFG.reg, scale=float(d or 1)).compile()
+    hlo = compiled.as_text()
+    aliased = re.search(r"input_output_alias=\{.*", hlo).group(0)
+    assert aliased.count("may-alias") == len(
+        ops.LaneState.__dataclass_fields__), aliased
+    assert not _full_size_copies(hlo, rf"{L},{M},{N}"), hlo
+
+
 def _all_reduces(hlo: str) -> collections.Counter:
     """All-reduces in a compiled HLO module by (where, result shape), with
     ``where`` as in ``_full_size_copies``."""
